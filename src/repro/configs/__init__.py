@@ -9,7 +9,7 @@ from __future__ import annotations
 
 from importlib import import_module
 
-from .base import SHAPES, LayerSpec, ModelConfig, ShapeSpec, uniform_program  # noqa: F401
+from .base import SHAPES, LayerSpec, ModelConfig, ShapeSpec, cut_depth, uniform_program  # noqa: F401
 from .specs import cache_specs, input_specs, supports_shape  # noqa: F401
 
 ARCHS: dict[str, str] = {
@@ -42,3 +42,16 @@ def get_smoke_config(arch: str) -> ModelConfig:
 
 def list_archs() -> list[str]:
     return list(ARCHS)
+
+
+def launch_config(arch: str, *, smoke: bool = False, layers: int | None = None) -> ModelConfig:
+    """The config a launcher runs: published (or ``smoke``), cut to its
+    first ``layers`` layers when given (see ``cut_depth``)."""
+    cfg = get_smoke_config(arch) if smoke else get_config(arch)
+    return cfg if layers is None else cut_depth(cfg, layers)
+
+
+def config_tag(*, smoke: bool = False, layers: int | None = None) -> str:
+    """Step-signature suffix naming a ``launch_config`` variant, so plans of
+    a smoke or depth-cut model never alias the published model's."""
+    return (":smoke" if smoke else "") + ("" if layers is None else f":L{layers}")

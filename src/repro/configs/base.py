@@ -141,3 +141,28 @@ SHAPES: dict[str, ShapeSpec] = {
 
 def uniform_program(spec: LayerSpec, n: int) -> tuple[Segment, ...]:
     return ((tuple([spec]), n),)
+
+
+def cut_depth(cfg: ModelConfig, layers: int) -> ModelConfig:
+    """The first ``layers`` layers of ``cfg`` at unchanged widths.
+
+    The program is cut in whole periods: a segment's unit (one layer for a
+    uniform program, the local/global pattern for gemma) is never split, so
+    the cut model keeps the published layer mix.  The encoder of an
+    encoder-decoder model keeps its depth.
+    """
+    if not 1 <= layers <= cfg.num_layers:
+        raise ValueError(f"{cfg.name}: --layers {layers} not in [1, {cfg.num_layers}]")
+    program: list[Segment] = []
+    left = layers
+    for unit, reps in cfg.program:
+        if left == 0:
+            break
+        take = min(reps, left // len(unit))
+        if take * len(unit) < min(left, reps * len(unit)):
+            raise ValueError(
+                f"{cfg.name}: --layers {layers} splits a period of {len(unit)} layers"
+            )
+        program.append((unit, take))
+        left -= take * len(unit)
+    return dataclasses.replace(cfg, num_layers=layers, program=tuple(program))

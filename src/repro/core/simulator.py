@@ -64,6 +64,21 @@ TPU_V5E = HardwareSpec(
     "tpu_v5e", peak_flops=197e12, hbm_bw=819e9, link_bw=50e9, efficiency=0.5,
     ici_bw=100e9,
 )
+# The specs that describe a chip JAX can report, keyed by its ``device_kind``.
+DEVICE_KINDS: dict[str, HardwareSpec] = {"TPU v5 lite": TPU_V5E}
+
+
+def hardware_for(device_kind: str) -> HardwareSpec:
+    """The spec the planner plans a device with; a kind no spec describes
+    is an error, not a default."""
+    if device_kind not in DEVICE_KINDS:
+        raise ValueError(
+            f"no HardwareSpec describes device kind {device_kind!r}; "
+            f"known: {sorted(DEVICE_KINDS)}"
+        )
+    return DEVICE_KINDS[device_kind]
+
+
 # cudaMalloc-style allocation cost used for the Table I speedup reproduction.
 CUDA_MALLOC_COST_S = 180e-6
 POOL_LOOKUP_COST_S = 0.4e-6

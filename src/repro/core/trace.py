@@ -10,10 +10,11 @@ Two ways to obtain the event stream the planner needs:
 
 2. ``trace_jaxpr`` — the TPU/JAX adaptation.  Under XLA the "iterative nature"
    is compiled-in: one ``jax.make_jaxpr(step_fn)`` IS the canonical iteration.
-   We walk the jaxpr as a virtual interpreter (inlining scan/while/cond/pjit
-   bodies the number of times they execute) and emit the same event stream a
-   runtime recorder would have seen: MALLOC+WRITE at producer, READ at each
-   consumer, FREE after last use (refcount semantics).  This gives the
+   We walk the jaxpr as a virtual interpreter (inlining scan/while/cond/jit/
+   shard_map bodies the number of times they execute) and emit the same
+   event stream a runtime recorder would have seen: MALLOC+WRITE at
+   producer, READ at each consumer, FREE after last use (refcount
+   semantics).  This gives the
    offline-DSA instance for *any* jitted step function — every architecture
    in configs/ goes through this path.
 """
@@ -174,6 +175,14 @@ def _eqn_cost(eqn) -> tuple[float, float]:
     return (flops, bytes_touched)
 
 
+# Primitives whose body jaxpr runs once per call: inlined, so the temporaries
+# of nested jits (jax.nn.silu, softmax), remat regions, custom derivatives and
+# shard_map bodies reach the planner as variables of their own.
+_CALL_PRIMS = frozenset(
+    {"jit", "shard_map", "closed_call", "remat2", "custom_jvp_call", "custom_vjp_call"}
+)
+
+
 class _JaxprEventEmitter:
     """Virtual interpreter over a ClosedJaxpr that emits the event stream."""
 
@@ -267,13 +276,9 @@ class _JaxprEventEmitter:
             self._run_jaxpr(branch.jaxpr, inner_env)
             self._bind_outputs(eqn, env)
             return
-        if prim in ("pjit", "closed_call", "core_call", "remat", "remat2", "checkpoint",
-                    "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr"):
-            sub = (eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
-                   or eqn.params.get("fun_jaxpr"))
-            if sub is not None:
-                self._run_call(eqn, env, sub)
-                return
+        if prim in _CALL_PRIMS:
+            self._run_call(eqn, env, eqn.params.get("jaxpr") or eqn.params["call_jaxpr"])
+            return
         # Default: a primitive compute op.
         self._read_inputs(eqn, env)
         cost_index = self._index  # the eqn's cost is charged to its first output

@@ -497,7 +497,9 @@ def capture_sharded_trace(
     """
     import jax
 
-    closed = jax.make_jaxpr(fn)(*example_args)
+    # The mesh's axes are bound while tracing, so a step written with named
+    # collectives (lax.psum over "data") traces as it would under shard_map.
+    closed = jax.make_jaxpr(fn, axis_env=list(mesh.axes))(*example_args)
     arg_divisors = None
     if in_specs is not None:
         arg_divisors = divisors_from_specs(example_args, in_specs, mesh)

@@ -26,6 +26,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .backend import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -112,8 +115,7 @@ def flash_attention(
     Requires Sq % block_q == 0 and Sk % block_k == 0 (ops.py picks divisors
     or falls back to the reference).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     B, Sq, H, hd = q.shape
     _, Sk, KV, _ = k.shape
     assert Sq % block_q == 0 and Sk % block_k == 0, (Sq, Sk, block_q, block_k)
@@ -142,31 +144,13 @@ def flash_attention(
         out_specs=pl.BlockSpec((1, 1, block_q, hd), lambda b, h, iq, ik: (b, h, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
         scratch_shapes=[
-            pl.MemorySpace.ANY if False else _vmem((block_q, 128), jnp.float32),
-            _vmem((block_q, 128), jnp.float32),
-            _vmem((block_q, hd), jnp.float32),
+            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, hd), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_tpu_params(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+        ),
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)
-
-
-def _vmem(shape, dtype):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.VMEM(shape, dtype)
-    except Exception:  # interpret-only environments
-        return pl.MemorySpace.ANY  # pragma: no cover
-
-
-def _tpu_params():
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-        )
-    except Exception:  # pragma: no cover
-        return None

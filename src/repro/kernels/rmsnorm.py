@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .backend import resolve_interpret
+
 
 def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)
@@ -25,8 +27,7 @@ def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):
 def rmsnorm(x, scale, *, eps: float = 1e-6, block_rows: int = 256,
             interpret: bool | None = None):
     """x [..., D], scale [D] -> same shape/dtype as x."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     orig_shape = x.shape
     d = x.shape[-1]
     rows = 1

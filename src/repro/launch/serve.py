@@ -14,21 +14,43 @@ by (arch, step signature, hardware): a second serving process — e.g. a
 decode worker next to a prefill worker, or the next restart — restores the
 solved plan from DIR instead of re-tracing the step.
 
+``--layers N`` serves the first N layers at published widths (whole
+periods of the layer program), leaving room for the KV cache on one chip.
+
 Usage:
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b --smoke \\
       --batch 4 --prompt-len 32 --gen 16 [--plan] [--plan-cache /tmp/plans]
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b --layers 2 \\
+      --batch 4 --prompt-len 512 --gen 8 --plan
 """
 
 from __future__ import annotations
 
 import argparse
 import time
+from dataclasses import dataclass
+from typing import Any
 
 import jax
 import jax.numpy as jnp
 
-from repro.configs import get_config, get_smoke_config, list_archs
+from repro.configs import config_tag, launch_config, list_archs
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
+
+
+@dataclass
+class ServeRun:
+    """One ``main`` call: the generated tokens, plus what a caller needs to
+    check the decode path against a prefill (the model, its parameters, the
+    prompt, the cache length and the logits of the first decode step)."""
+
+    tokens: jax.Array                    # [B, gen] greedy ids
+    first_decode_logits: jax.Array | None  # [B, 1, V]; None when gen == 1
+    prompt: dict
+    model: Any
+    params: Any
+    max_seq: int
 
 
 def serve_batch_struct(cfg, B: int, P: int) -> dict:
@@ -69,10 +91,10 @@ def plan_serve_steps(model, cfg, args, max_seq: int, plan_cache=None):
         "prefill": (prefill_fn, (pshapes, batch)),
         "decode": (model.decode_step, (pshapes, cache_struct, tok, pos)),
     }
-    smoke = ":smoke" if args.smoke else ""
+    tag = config_tag(smoke=args.smoke, layers=args.layers)
     out = {}
     for role, (fn, fargs) in steps.items():
-        key = PlanKey(args.arch, f"{role}:b{B}p{P}s{max_seq}{smoke}", TPU_V5E.name)
+        key = PlanKey(args.arch, f"{role}:b{B}p{P}s{max_seq}{tag}", TPU_V5E.name)
         planner = MemoryPlanner(
             fn, *fargs, hw=TPU_V5E, cache=plan_cache, key=key, size_threshold=1 << 18
         )
@@ -99,6 +121,9 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list_archs(), default="qwen3-4b")
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="keep the first N layers at published widths "
+                         "(default: the config's full depth)")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
@@ -121,7 +146,8 @@ def main(argv=None):
     add_obs_args(ap)
     args = ap.parse_args(argv)
 
-    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    use_compile_cache()
+    cfg = launch_config(args.arch, smoke=args.smoke, layers=args.layers)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(args.seed))
 
@@ -180,22 +206,27 @@ def main(argv=None):
     prefill = jax.jit(lambda p, b: model.prefill(p, b, max_seq=max_seq))
     logits, cache = prefill(params, batch)
     next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
+    jax.block_until_ready((next_tok, cache))
     print(f"prefill: {B}x{P} in {time.time()-t0:.2f}s")
 
     decode = jax.jit(model.decode_step, donate_argnums=(1,))
     pos0 = P + (min(cfg.num_patch_tokens, 8) if cfg.frontend == "vision_stub" else 0)
     out_tokens = [next_tok]
+    first_decode_logits = None
     t0 = time.time()
     for i in range(args.gen - 1):
         logits, cache = decode(params, cache, next_tok, jnp.asarray(pos0 + i, jnp.int32))
+        if i == 0:
+            first_decode_logits = logits
         next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
         out_tokens.append(next_tok)
+    jax.block_until_ready((next_tok, cache))
     dt = time.time() - t0
     gen = jnp.concatenate(out_tokens, axis=1)
     print(f"decode: {args.gen} tokens x {B} seqs in {dt:.2f}s "
           f"({B*(args.gen-1)/max(dt,1e-9):.1f} tok/s)")
     print("sample:", gen[0, :12].tolist())
-    return gen
+    return ServeRun(gen, first_decode_logits, batch, model, params, max_seq)
 
 
 if __name__ == "__main__":

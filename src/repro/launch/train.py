@@ -12,6 +12,8 @@ AdamW, checkpoint/restart, and the paper's memory planner wired in:
   * ``--hbm-limit``  GB budget per device: AutoSwap picks the activation
                      classes to offload (pinned_host) and the train step is
                      rebuilt with that remat policy (§IV applied via XLA).
+  * ``--layers N``   the first N layers at published widths (whole periods
+                     of the layer program), so a large model fits one chip.
 
 Fault tolerance:
   * atomic keep-k checkpoints (async), auto-resume from the latest step;
@@ -25,24 +27,37 @@ Fault tolerance:
 Usage:
   PYTHONPATH=src python -m repro.launch.train --arch qwen3-4b --smoke \\
       --steps 200 --batch 8 --seq 128 --ckpt-dir /tmp/ckpt
+  PYTHONPATH=src python -m repro.launch.train --arch qwen3-4b --layers 2 \\
+      --batch 1 --seq 2048 --steps 3 --hbm-limit-gb 2
 """
 
 from __future__ import annotations
 
 import argparse
 import time
+from dataclasses import dataclass, field
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import CheckpointManager
-from repro.configs import get_config, get_smoke_config, list_archs
+from repro.configs import config_tag, launch_config, list_archs
 from repro.core.planner import MemoryPlanner
-from repro.data import Prefetcher, SyntheticTokens
+from repro.data import SyntheticTokens
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.steps import build_train_step
 from repro.models import build_model
 from repro.optim import adamw_init
-from repro.launch.steps import build_train_step
+
+
+@dataclass
+class TrainRun:
+    """What one ``main`` call did: the loss of every step it ran and the
+    activation classes its step offloaded to host memory."""
+
+    losses: list[float]
+    offload_names: list[str] = field(default_factory=list)
 
 
 def make_batch_fn(cfg, batch: int, seq: int, seed: int):
@@ -68,6 +83,9 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list_archs(), default="qwen3-4b")
     ap.add_argument("--smoke", action="store_true", help="reduced config (CPU-runnable)")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="keep the first N layers at published widths "
+                         "(default: the config's full depth)")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -91,7 +109,8 @@ def main(argv=None):
     add_obs_args(ap)
     args = ap.parse_args(argv)
 
-    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    use_compile_cache()
+    cfg = launch_config(args.arch, smoke=args.smoke, layers=args.layers)
     model = build_model(cfg)
     batch_fn = make_batch_fn(cfg, args.batch, args.seq, args.seed)
 
@@ -108,8 +127,8 @@ def main(argv=None):
         mesh = MeshSpec.parse(args.dist_plan)
         step_probe, example_args = probe_from_model(model, batch_fn)
         capture = capture_for_mesh(cfg, step_probe, example_args, mesh, TPU_V5E)
-        smoke = ":smoke" if args.smoke else ""
-        base_key = PlanKey(args.arch, f"train:b{args.batch}s{args.seq}{smoke}", TPU_V5E.name)
+        tag = config_tag(smoke=args.smoke, layers=args.layers)
+        base_key = PlanKey(args.arch, f"train:b{args.batch}s{args.seq}{tag}", TPU_V5E.name)
         dist_cache = PlanCache(args.plan_cache) if args.plan_cache else None
         solved = solve_sharded(
             capture, TPU_V5E, base_key=base_key, cache=dist_cache,
@@ -161,6 +180,7 @@ def main(argv=None):
                               recorder=recorder, report=mesh_run.report)
 
     remat_policy = None
+    offload_names: list[str] = []
     if args.plan or args.plan_cache or args.hbm_limit_gb is not None:
         from repro.core.simulator import TPU_V5E
         from repro.plan import PlanCache, PlanKey
@@ -172,8 +192,8 @@ def main(argv=None):
             return model.loss(params, batch)[0]
 
         plan_cache = PlanCache(args.plan_cache) if args.plan_cache else None
-        smoke = ":smoke" if args.smoke else ""
-        key = PlanKey(args.arch, f"train:b{args.batch}s{args.seq}{smoke}", TPU_V5E.name)
+        tag = config_tag(smoke=args.smoke, layers=args.layers)
+        key = PlanKey(args.arch, f"train:b{args.batch}s{args.seq}{tag}", TPU_V5E.name)
         planner = MemoryPlanner(step_probe, pshapes, probe, hw=TPU_V5E,
                                 cache=plan_cache, key=key)
         rep = planner.report()
@@ -192,6 +212,7 @@ def main(argv=None):
                 f"simulated overhead {sw.overhead*100:.2f}%)"
             )
             remat_policy = plan.policy()
+            offload_names = list(plan.offload_names)
 
     train_step = build_train_step(model, cfg, lr=args.lr, remat_policy=remat_policy)
     jit_step = jax.jit(train_step, donate_argnums=(0, 1))
@@ -214,8 +235,9 @@ def main(argv=None):
         t0 = time.time()
         batch = batch_fn(step)
         params, opt, metrics = jit_step(params, opt, batch, jnp.asarray(step, jnp.int32))
-        loss = float(metrics["loss"])
+        jax.block_until_ready((params, opt, metrics))
         dt = time.time() - t0
+        loss = float(metrics["loss"])
         if len(times) >= 5 and dt > args.step_timeout * float(np.median(times)):
             stragglers += 1
             print(f"[watchdog] step {step} took {dt:.2f}s (median {np.median(times):.2f}s)")
@@ -232,7 +254,7 @@ def main(argv=None):
         f"done: first-loss {losses[0]:.4f} last-loss {losses[-1]:.4f} "
         f"stragglers={stragglers}"
     )
-    return losses
+    return TrainRun(losses, offload_names)
 
 
 if __name__ == "__main__":

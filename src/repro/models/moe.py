@@ -81,7 +81,6 @@ def apply_moe_shardmap(p, x, cfg: ModelConfig):
     rank computed its seq slice); the residual add re-gathers it.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     from repro.distributed.sharding import _CTX, batch_axes
 
@@ -159,12 +158,12 @@ def apply_moe_shardmap(p, x, cfg: ModelConfig):
                    P("model", "data", None))
     else:
         w_specs = (P("model", None, None),) * 3
-    out = shard_map(
+    out = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(bspec, P(None, None)) + w_specs,
         out_specs=P(bspec[0], "model", None),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 
     aux = jnp.zeros((), jnp.float32)  # load-balance loss skipped in EP mode

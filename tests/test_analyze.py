@@ -14,7 +14,8 @@ Two halves:
 import dataclasses
 from pathlib import Path
 
-from repro.testing import given, settings, st  # hypothesis or deterministic fallback
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.analyze import (
     Certificate,

@@ -1,0 +1,137 @@
+"""Compiles of the main path for a described (not attached) TPU v5e.
+
+The TPU compiler is installed with JAX and compiles for a chip described by
+``topologies.get_topology_desc``: it refuses what the chip would refuse
+(tile-misaligned kernel blocks, programs that do not fit HBM), which
+interpret mode cannot show.  Nothing runs, so these say nothing about
+results or times.
+
+The topology is described inside a module fixture, never at import: only one
+process at a time may load the TPU library, and every test worker imports
+this file.  Where it cannot be described, the fixture skips.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import launch_config
+from repro.core.offload import OffloadPlan
+from repro.core.planner import MemoryPlanner
+from repro.core.simulator import TPU_V5E
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.rmsnorm import rmsnorm
+from repro.kernels.ssd_scan import ssd_scan
+from repro.launch.steps import build_train_step, init_optimizer_shapes
+from repro.launch.train import make_batch_fn
+from repro.models import build_model
+
+HBM_BYTES = 16 * 2**30
+# chip_smoke.py's train phase: qwen3-4b cut to 2 layers, batch 1 x 2048
+# positions, at an HBM budget of 2 GiB.
+TRAIN_LAYERS, TRAIN_BATCH, TRAIN_SEQ, TRAIN_LIMIT = 2, 1, 2048, 2 * 2**30
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One described chip, with the persistent compile cache off: a compile
+    for a described chip is written to it but cannot be read back."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def _on(sharding, tree):
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding), tree
+    )
+
+
+def _compile_kernel(fn, sharding, *shapes):
+    compiled = jax.jit(fn).lower(*_on(sharding, shapes)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def test_flash_attention_compiles_at_qwen3_4b_widths(one_chip):
+    q = jax.ShapeDtypeStruct((1, 4096, 32, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 4096, 8, 128), jnp.bfloat16)
+    _compile_kernel(
+        lambda q, k, v: flash_attention(q, k, v, causal=True, interpret=False),
+        one_chip, q, kv, kv,
+    )
+
+
+def test_ssd_scan_compiles_at_mamba2_370m_widths(one_chip):
+    b, s, h, p, g, n = 1, 4096, 32, 64, 1, 128
+    f32 = jnp.float32
+    _compile_kernel(
+        lambda *a: ssd_scan(*a, chunk=256, interpret=False),
+        one_chip,
+        jax.ShapeDtypeStruct((b, s, h, p), f32),
+        jax.ShapeDtypeStruct((b, s, h), f32),
+        jax.ShapeDtypeStruct((h,), f32),
+        jax.ShapeDtypeStruct((b, s, g, n), f32),
+        jax.ShapeDtypeStruct((b, s, g, n), f32),
+    )
+
+
+def test_rmsnorm_compiles_at_d2560(one_chip):
+    _compile_kernel(
+        lambda x, s: rmsnorm(x, s, interpret=False),
+        one_chip,
+        jax.ShapeDtypeStruct((4096, 2560), jnp.bfloat16),
+        jax.ShapeDtypeStruct((2560,), jnp.float32),
+    )
+
+
+def test_offloaded_train_step_compiles_and_fits(one_chip):
+    """The 2-layer qwen3-4b step with the plan's pinned_host offload policy
+    fits one chip's HBM and keeps temporaries in host memory."""
+    cfg = launch_config("qwen3-4b", layers=TRAIN_LAYERS)
+    model = build_model(cfg)
+    pshapes = model.init_shapes()
+    batch_fn = make_batch_fn(cfg, TRAIN_BATCH, TRAIN_SEQ, 0)
+    batch = jax.eval_shape(lambda: batch_fn(0))
+    planner = MemoryPlanner(
+        lambda p, b: model.loss(p, b)[0], pshapes, batch, hw=TPU_V5E
+    )
+    plan: OffloadPlan = planner.offload_plan(TRAIN_LIMIT)
+    assert plan.offload_names
+
+    step = build_train_step(model, cfg, remat_policy=plan.policy())
+    args = _on(one_chip, (
+        pshapes, init_optimizer_shapes(pshapes), batch,
+        jax.ShapeDtypeStruct((), jnp.int32),
+    ))
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.host_temp_size_in_bytes > 0
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes <= HBM_BYTES
+
+
+def test_described_chip_is_the_planners_v5e(topo):
+    from repro.core.simulator import hardware_for
+
+    assert hardware_for(topo.devices[0].device_kind) is TPU_V5E
+    with pytest.raises(ValueError, match="no HardwareSpec"):
+        hardware_for("TPU v4")

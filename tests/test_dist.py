@@ -137,13 +137,10 @@ def test_collective_tagging_from_jaxpr_psum():
 
     x = jax.ShapeDtypeStruct((32, 64), jnp.float32)
     m = MeshSpec.make(data=4)
-    try:
-        cap = capture_sharded_trace(
-            traced, x, mesh=m, hw=HW,
-            in_specs=(P("data", None),), arg_names=["x"],
-        )
-    except Exception:
-        pytest.skip("jaxpr tracing of unbound psum unsupported in this jax")
+    cap = capture_sharded_trace(
+        traced, x, mesh=m, hw=HW,
+        in_specs=(P("data", None),), arg_names=["x"],
+    )
     group = cap.groups["spmd"]
     assert group.collectives, "psum eqn not tagged"
     c = group.collectives[0]
@@ -437,14 +434,13 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp
 from functools import partial
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.launch.mesh import make_mesh
 from repro.dist import MeshSpec, capture_sharded_trace
 
 mesh = make_mesh((4,), ("data",))
 
-@partial(shard_map, mesh=mesh, in_specs=(P(None, None), P("data", None)),
-         out_specs=P(None, None), check_rep=False)
+@partial(jax.shard_map, mesh=mesh, in_specs=(P(None, None), P("data", None)),
+         out_specs=P(None, None), check_vma=False)
 def step(w, x):
     h = jax.nn.relu(x @ w)
     g = jax.lax.psum(h.T @ h, "data")

@@ -22,7 +22,8 @@ from repro.runtime import _engine_reference as ref
 from repro.runtime import engine as fast
 from repro.runtime.engine import planned_peak, simulated_report_dict
 from repro.runtime.workload import poisson_workload, synthetic_train_trace
-from repro.testing import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 HW = GTX_1080TI
 SIZE_THRESHOLD = 1 << 20

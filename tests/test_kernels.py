@@ -4,7 +4,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from repro.testing import given, settings, st  # hypothesis or deterministic fallback
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
@@ -129,3 +130,18 @@ def test_rmsnorm_bf16_and_3d():
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(exp, np.float32), rtol=2e-2, atol=2e-2
     )
+
+
+@pytest.mark.parametrize("backend,want", [("cpu", True), ("tpu", False), ("gpu", None)])
+def test_interpret_default_follows_backend(monkeypatch, backend, want):
+    """Interpret mode by default only on the CPU; a backend that is neither
+    CPU nor TPU is an error, never a silent fallback."""
+    from repro.kernels import backend as kb
+
+    monkeypatch.setattr(kb.jax, "default_backend", lambda: backend)
+    if want is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            kb.resolve_interpret(None)
+    else:
+        assert kb.resolve_interpret(None) is want
+    assert kb.resolve_interpret(False) is False

@@ -6,7 +6,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import get_config, get_smoke_config, list_archs, SHAPES, input_specs, supports_shape
+from repro.configs import (
+    SHAPES, cut_depth, get_config, get_smoke_config, input_specs, launch_config,
+    list_archs, supports_shape,
+)
 from repro.models import build_model
 
 
@@ -110,3 +113,27 @@ def test_param_counts_in_expected_band():
         shapes = model.init_shapes()
         n = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(shapes))
         assert lo < n < hi, f"{arch}: {n/1e9:.2f}B params out of band ({lo/1e9}-{hi/1e9})"
+
+
+@pytest.mark.parametrize("arch,layers,program", [
+    ("qwen3-4b", 2, ((1, 2),)),
+    ("gemma3-4b", 12, ((6, 2),)),
+    ("gemma3-4b", 34, ((6, 5), (4, 1))),
+    ("hymba-1.5b", 17, ((1, 1), (1, 15), (1, 1))),
+])
+def test_cut_depth_keeps_widths_in_whole_periods(arch, layers, program):
+    full = get_config(arch)
+    cut = launch_config(arch, layers=layers)
+    assert cut.num_layers == layers
+    assert tuple((len(unit), reps) for unit, reps in cut.program) == program
+    assert [unit for unit, _ in cut.program] == [unit for unit, _ in full.program][: len(program)]
+    for f in ("d_model", "num_heads", "num_kv_heads", "head_dim", "d_ff", "vocab_size"):
+        assert getattr(cut, f) == getattr(full, f)
+    assert launch_config(arch) == full
+
+
+@pytest.mark.parametrize("arch,layers", [("gemma3-4b", 3), ("gemma2-9b", 5), ("qwen3-4b", 0),
+                                         ("qwen3-4b", 37)])
+def test_cut_depth_rejects_split_periods_and_bad_counts(arch, layers):
+    with pytest.raises(ValueError):
+        cut_depth(get_config(arch), layers)

@@ -4,7 +4,7 @@ Invariants pinned here:
 
   1. The quantile sketch answers within its *self-reported* rank-error
      bound on adversarial streams (sorted, reversed, constant,
-     heavy-tailed, random) — property-tested via the repro.testing shim —
+     heavy-tailed, random) — property-tested with hypothesis —
      and its state is a pure function of the input stream (deterministic
      compaction and merge).
   2. Window machinery handles the boundary cases: empty windows emit
@@ -55,7 +55,8 @@ from repro.runtime import _engine_reference as ref
 from repro.runtime import engine as fast
 from repro.runtime.engine import planned_peak, simulated_report_dict
 from repro.runtime.workload import poisson_workload, synthetic_train_trace
-from repro.testing import given, settings, st  # hypothesis or deterministic fallback
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 HW = GTX_1080TI
 SIZE_THRESHOLD = 1 << 20

@@ -11,7 +11,8 @@ Covers the runtime tentpole:
 """
 
 import pytest
-from repro.testing import given, settings, st  # hypothesis or deterministic fallback
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core._solver_reference import reference_simulate_swap_schedule
 from repro.core.autoswap import AutoSwapPlanner

@@ -2,7 +2,8 @@
 
 import numpy as np
 import pytest
-from repro.testing import given, settings, st  # hypothesis or deterministic fallback
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.events import Event, EventKind, IterationTrace, VariableInfo, build_trace
 from repro.core.baseline_pools import CnMemPool, exact_allocator

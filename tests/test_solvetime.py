@@ -14,7 +14,8 @@ originals.  These tests pin:
 
 import numpy as np
 import pytest
-from repro.testing import given, settings, st  # hypothesis or deterministic fallback
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core._solver_reference import ReferenceAutoSwapPlanner, reference_solve
 from repro.core.autoswap import AutoSwapPlanner

@@ -104,3 +104,30 @@ def test_checkpoint_name_labels_survive():
     tr = trace_step_fn(step, w, x)
     names = {v.name for v in tr.variables}
     assert "block_in" in names
+
+
+@pytest.mark.parametrize("wrap", ["jit", "shard_map", "custom_jvp"])
+def test_call_bodies_are_inlined(wrap):
+    """Nested jit (jax.nn.silu), shard_map and custom-derivative bodies are
+    walked: their temporaries become variables of their own, and no opaque
+    call primitive is left standing in for them."""
+    from functools import partial
+
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    def inner(x):
+        return jnp.tanh(x) * x + 1.0
+
+    if wrap == "jit":
+        f = jax.jit(inner)
+    elif wrap == "shard_map":
+        mesh = Mesh(jax.devices()[:1], ("data",))
+        f = partial(jax.shard_map, mesh=mesh, in_specs=P("data"),
+                    out_specs=P("data"), check_vma=False)(inner)
+    else:
+        f = jax.nn.silu
+
+    x = jax.ShapeDtypeStruct((16, 32), jnp.float32)
+    names = [v.name for v in trace_step_fn(lambda a: f(a).sum(), x).variables]
+    assert not {"jit", "shard_map", "custom_jvp_call"} & set(names)
+    assert len(names) >= 4  # the input, >=2 body temporaries, the sum

@@ -366,7 +366,6 @@ def test_directional_link_report_keys_gated():
 
 
 def test_run_mesh_directional_probe_and_carve():
-    pytest.importorskip("jax")
     from repro.dist import run_mesh
     from test_dist import _solved_toy
 
