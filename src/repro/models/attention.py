@@ -63,7 +63,14 @@ def _softcap(scores, cap):
 
 
 def _expand_kv(k, G: int):
-    """[B,S,KV,hd] -> [B,S,KV*G,hd] broadcast (fused into the matmul by XLA)."""
+    """[B,S,KV,hd] -> [B,S,KV*G,hd] broadcast.
+
+    On a TPU v5e XLA materialises this broadcast instead of fusing it into
+    the matmul that reads it: the decode step writes the expanded K and V out
+    whole every step (in a profile of qwen3-4b decode at batch 4 over a
+    16,896-position cache, a ``broadcast_in_dim`` to ``bf16[4,16896,8,4,128]``
+    of 10.9 ms a step for each of K and V).
+    """
     if G == 1:
         return k
     B, S, KV, hd = k.shape
@@ -79,23 +86,23 @@ def _sdpa(q, k, v, mask, cfg: ModelConfig):
     (context-parallel / split-KV) — otherwise scores replicate at
     O(S^2 * H) per device.
     """
-    B, Sq, H, hd = q.shape
-    G = H // k.shape[2]
-    k, v = _expand_kv(k, G), _expand_kv(v, G)
-    scores = jnp.einsum("bqhd,bshd->bhqs", q, k) * _scale(cfg)
-    scores = _softcap(scores.astype(jnp.float32), cfg.attn_softcap)
-    if mask is not None:
-        scores = jnp.where(mask, scores, -1e30)
-    scores = shard_pick(
-        scores,
-        ("batch", "heads", None, None),
-        ("batch_full", None, None, None),
-        ("batch", None, "seq_model", None),
-        ("batch", None, None, "seq_model"),
-    )
-    w = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bhqs,bshd->bqhd", w, v)
-    return out
+    with jax.named_scope("sdpa"):
+        B, Sq, H, hd = q.shape
+        G = H // k.shape[2]
+        k, v = _expand_kv(k, G), _expand_kv(v, G)
+        scores = jnp.einsum("bqhd,bshd->bhqs", q, k) * _scale(cfg)
+        scores = _softcap(scores.astype(jnp.float32), cfg.attn_softcap)
+        if mask is not None:
+            scores = jnp.where(mask, scores, -1e30)
+        scores = shard_pick(
+            scores,
+            ("batch", "heads", None, None),
+            ("batch_full", None, None, None),
+            ("batch", None, "seq_model", None),
+            ("batch", None, None, "seq_model"),
+        )
+        w = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        return jnp.einsum("bhqs,bshd->bqhd", w, v)
 
 
 def _causal_window_mask(q_pos, k_pos, causal: bool, window: int | None):
@@ -154,6 +161,29 @@ def _round_up(x, m):
     return (x + m - 1) // m * m
 
 
+# ------------------------------------------------------------ projections
+def _project_qkv(p, x, cfg: ModelConfig, angles):
+    """x [B,S,D] -> q [B,S,H,hd], k and v [B,S,KV,hd]: projections, qk-norm, RoPE."""
+    with jax.named_scope("qkv"):
+        dt = x.dtype
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
+        k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(dt))
+        v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(dt))
+        if cfg.qk_norm:
+            q = rmsnorm(p["q_norm"], q, cfg.norm_eps)
+            k = rmsnorm(p["k_norm"], k, cfg.norm_eps)
+        if angles is not None:
+            q = apply_rope(q, angles)
+            k = apply_rope(k, angles)
+        return q, k, v
+
+
+def _project_out(p, out, dt):
+    """[B,S,H,hd] -> [B,S,D]."""
+    with jax.named_scope("out_proj"):
+        return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
+
+
 # ----------------------------------------------------------- train/prefill
 def apply_attention(
     p,
@@ -166,22 +196,13 @@ def apply_attention(
     impl: str | None = None,
 ):
     """Full-sequence attention (training / prefill). Returns [B,S,D]."""
-    dt = x.dtype
-    B, S, _ = x.shape
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
-    k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(dt))
-    v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(dt))
-    if cfg.qk_norm:
-        q = rmsnorm(p["q_norm"], q, cfg.norm_eps)
-        k = rmsnorm(p["k_norm"], k, cfg.norm_eps)
-    if angles is not None:
-        q = apply_rope(q, angles)
-        k = apply_rope(k, angles)
+    S = x.shape[1]
+    q, k, v = _project_qkv(p, x, cfg, angles)
     window = spec.window if spec.attn in ("window", "hybrid") else None
     use_chunked = impl == "chunked" or (impl is None and S >= CHUNKED_THRESHOLD)
     fn = mha_chunked if use_chunked else mha_dense
     out = fn(q, k, v, cfg, causal=causal, window=window)
-    return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
+    return _project_out(p, out, x.dtype)
 
 
 def apply_cross_attention(p, x, enc_kv, cfg: ModelConfig):
@@ -211,32 +232,25 @@ def prefill_attention(p, x, cfg: ModelConfig, spec: LayerSpec, angles, max_seq: 
     """Full-sequence attention that also emits the filled KV cache."""
     dt = x.dtype
     B, S, _ = x.shape
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
-    k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(dt))
-    v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(dt))
-    if cfg.qk_norm:
-        q = rmsnorm(p["q_norm"], q, cfg.norm_eps)
-        k = rmsnorm(p["k_norm"], k, cfg.norm_eps)
-    if angles is not None:
-        q = apply_rope(q, angles)
-        k = apply_rope(k, angles)
+    q, k, v = _project_qkv(p, x, cfg, angles)
     window = spec.window if spec.attn in ("window", "hybrid") else None
     fn = mha_chunked if S >= CHUNKED_THRESHOLD else mha_dense
     out = fn(q, k, v, cfg, causal=True, window=window)
-    out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
+    out = _project_out(p, out, dt)
 
     W = cache_len(cfg, spec, max_seq)
-    if W >= S:
-        pad = [(0, 0), (0, W - S), (0, 0), (0, 0)]
-        cache = {"k": jnp.pad(k, pad), "v": jnp.pad(v, pad)}
-    else:
-        # Ring: slots hold the last W positions p in [S-W, S), slot = p % W.
-        pos = S - W + jnp.arange(W)
-        slots = pos % W
-        cache = {
-            "k": jnp.zeros((B, W) + k.shape[2:], dt).at[:, slots].set(k[:, pos]),
-            "v": jnp.zeros((B, W) + v.shape[2:], dt).at[:, slots].set(v[:, pos]),
-        }
+    with jax.named_scope("kv_write"):
+        if W >= S:
+            pad = [(0, 0), (0, W - S), (0, 0), (0, 0)]
+            cache = {"k": jnp.pad(k, pad), "v": jnp.pad(v, pad)}
+        else:
+            # Ring: slots hold the last W positions p in [S-W, S), slot = p % W.
+            pos = S - W + jnp.arange(W)
+            slots = pos % W
+            cache = {
+                "k": jnp.zeros((B, W) + k.shape[2:], dt).at[:, slots].set(k[:, pos]),
+                "v": jnp.zeros((B, W) + v.shape[2:], dt).at[:, slots].set(v[:, pos]),
+            }
     return out, cache
 
 
@@ -251,29 +265,19 @@ def init_kv_cache(cfg: ModelConfig, spec: LayerSpec, batch: int, max_seq: int, d
 
 def decode_attention(p, x, cache, pos, cfg: ModelConfig, spec: LayerSpec, angles):
     """One-token decode. x [B,1,D]; pos scalar int32; returns (out, new_cache)."""
-    dt = x.dtype
-    B = x.shape[0]
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
-    k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(dt))
-    v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(dt))
-    if cfg.qk_norm:
-        q = rmsnorm(p["q_norm"], q, cfg.norm_eps)
-        k = rmsnorm(p["k_norm"], k, cfg.norm_eps)
-    if angles is not None:
-        q = apply_rope(q, angles)
-        k = apply_rope(k, angles)
+    q, k, v = _project_qkv(p, x, cfg, angles)
 
     # Unified ring-buffer update: full caches are rings of size max_seq, so
     # slot == pos and the validity mask reduces to idx <= pos; window caches
     # wrap and the mask keeps exactly the last `window` positions.
     W = cache["k"].shape[1]
     slot = pos % W
-    new_k = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, slot, axis=1)
-    new_v = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, slot, axis=1)
+    with jax.named_scope("kv_write"):
+        new_k = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, slot, axis=1)
+        new_v = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, slot, axis=1)
 
     idx = jnp.arange(W)
     written_at = pos - jnp.mod(pos - idx, W)  # last write position of slot idx
     mask = written_at >= 0
     out = _sdpa(q, new_k, new_v, mask[None, None, None, :], cfg)
-    out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
-    return out, {"k": new_k, "v": new_v}
+    return _project_out(p, out, x.dtype), {"k": new_k, "v": new_v}

@@ -46,6 +46,13 @@ from .rope import mrope_angles, rope_angles
 
 # ---------------------------------------------------------------- layers
 
+# The named scope of each token-mixing block (``LayerSpec.attn``). Every block
+# of a layer runs under one scope that holds its norm, the block and its
+# residual add, so the compiled program's ``op_name`` metadata, and with it a
+# profile of the program, names the block an op belongs to.
+MIX_SCOPE = {"full": "attn", "window": "attn", "mla": "mla", "mamba": "mamba",
+             "hybrid": "hybrid"}
+
 
 def init_layer(key, cfg: ModelConfig, spec: LayerSpec):
     ks = jax.random.split(key, 6)
@@ -101,31 +108,35 @@ def apply_layer(p, x, cfg: ModelConfig, spec: LayerSpec, angles, enc_out=None, c
     """Returns (x, aux_loss)."""
     aux = jnp.zeros((), jnp.float32)
     if spec.attn != "none":
-        h = apply_norm(p["ln1"], x, cfg)
-        h = _mix(p, h, cfg, spec, angles, causal)
-        h = checkpoint_name(h, "attn_out")
-        if cfg.sandwich_norms:
-            h = apply_norm(p["ln1_post"], h, cfg)
-        x = x + h
+        with jax.named_scope(MIX_SCOPE[spec.attn]):
+            h = apply_norm(p["ln1"], x, cfg)
+            h = _mix(p, h, cfg, spec, angles, causal)
+            h = checkpoint_name(h, "attn_out")
+            if cfg.sandwich_norms:
+                h = apply_norm(p["ln1_post"], h, cfg)
+            x = x + h
     if spec.cross_attn:
-        h = apply_norm(p["ln_cross"], x, cfg)
-        kv = attn_mod.encode_cross_kv(p["cross"], enc_out, cfg)
-        h = attn_mod.apply_cross_attention(p["cross"], h, kv, cfg)
-        x = x + h
+        with jax.named_scope("cross_attn"):
+            h = apply_norm(p["ln_cross"], x, cfg)
+            kv = attn_mod.encode_cross_kv(p["cross"], enc_out, cfg)
+            h = attn_mod.apply_cross_attention(p["cross"], h, kv, cfg)
+            x = x + h
     if spec.ffn == "dense":
-        h = apply_norm(p["ln2"], x, cfg)
-        h = apply_dense_ffn(p["ffn"], h, cfg)
-        h = checkpoint_name(h, "ffn_out")
-        if cfg.sandwich_norms:
-            h = apply_norm(p["ln2_post"], h, cfg)
-        x = x + h
+        with jax.named_scope("ffn"):
+            h = apply_norm(p["ln2"], x, cfg)
+            h = apply_dense_ffn(p["ffn"], h, cfg)
+            h = checkpoint_name(h, "ffn_out")
+            if cfg.sandwich_norms:
+                h = apply_norm(p["ln2_post"], h, cfg)
+            x = x + h
     elif spec.ffn == "moe":
-        h = apply_norm(p["ln2"], x, cfg)
-        h, aux = moe_mod.apply_moe(p["moe"], h, cfg)
-        h = checkpoint_name(h, "ffn_out")
-        if cfg.sandwich_norms:
-            h = apply_norm(p["ln2_post"], h, cfg)
-        x = x + h
+        with jax.named_scope("moe"):
+            h = apply_norm(p["ln2"], x, cfg)
+            h, aux = moe_mod.apply_moe(p["moe"], h, cfg)
+            h = checkpoint_name(h, "ffn_out")
+            if cfg.sandwich_norms:
+                h = apply_norm(p["ln2_post"], h, cfg)
+            x = x + h
     x = shard(x, "batch", "seq", "embed")
     return x, aux
 
@@ -136,46 +147,32 @@ def apply_layer(p, x, cfg: ModelConfig, spec: LayerSpec, angles, enc_out=None, c
 def prefill_layer(p, x, cfg: ModelConfig, spec: LayerSpec, angles, max_seq: int, enc_out=None):
     """Forward one layer over the whole prompt, emitting its decode cache."""
     cache: dict[str, Any] = {}
-    if spec.attn in ("full", "window"):
-        h = apply_norm(p["ln1"], x, cfg)
-        h, cache["kv"] = attn_mod.prefill_attention(p["attn"], h, cfg, spec, angles, max_seq)
-        if cfg.sandwich_norms:
-            h = apply_norm(p["ln1_post"], h, cfg)
-        x = x + h
-    elif spec.attn == "mla":
-        h = apply_norm(p["ln1"], x, cfg)
-        h, cache["kv"] = mla_mod.prefill_mla(p["attn"], h, cfg, spec, angles, max_seq)
-        x = x + h
-    elif spec.attn == "mamba":
-        h = apply_norm(p["ln1"], x, cfg)
-        h, cache["ssm"] = ssm_mod.apply_mamba(p["mamba"], h, cfg, return_cache=True)
-        x = x + h
-    elif spec.attn == "hybrid":
-        h = apply_norm(p["ln1"], x, cfg)
-        a, cache["kv"] = attn_mod.prefill_attention(p["attn"], h, cfg, spec, angles, max_seq)
-        m, cache["ssm"] = ssm_mod.apply_mamba(p["mamba"], h, cfg, return_cache=True)
-        h = 0.5 * (
-            rmsnorm(p["branch_norm_a"], a, cfg.norm_eps)
-            + rmsnorm(p["branch_norm_m"], m, cfg.norm_eps)
-        )
-        x = x + h
+    if spec.attn != "none":
+        with jax.named_scope(MIX_SCOPE[spec.attn]):
+            h = apply_norm(p["ln1"], x, cfg)
+            if spec.attn in ("full", "window"):
+                h, cache["kv"] = attn_mod.prefill_attention(p["attn"], h, cfg, spec, angles, max_seq)
+                if cfg.sandwich_norms:
+                    h = apply_norm(p["ln1_post"], h, cfg)
+            elif spec.attn == "mla":
+                h, cache["kv"] = mla_mod.prefill_mla(p["attn"], h, cfg, spec, angles, max_seq)
+            elif spec.attn == "mamba":
+                h, cache["ssm"] = ssm_mod.apply_mamba(p["mamba"], h, cfg, return_cache=True)
+            else:  # hybrid
+                a, cache["kv"] = attn_mod.prefill_attention(p["attn"], h, cfg, spec, angles, max_seq)
+                m, cache["ssm"] = ssm_mod.apply_mamba(p["mamba"], h, cfg, return_cache=True)
+                h = 0.5 * (
+                    rmsnorm(p["branch_norm_a"], a, cfg.norm_eps)
+                    + rmsnorm(p["branch_norm_m"], m, cfg.norm_eps)
+                )
+            x = x + h
     if spec.cross_attn:
-        cache["enc_kv"] = attn_mod.encode_cross_kv(p["cross"], enc_out, cfg)
-        h = apply_norm(p["ln_cross"], x, cfg)
-        h = attn_mod.apply_cross_attention(p["cross"], h, cache["enc_kv"], cfg)
-        x = x + h
-    if spec.ffn == "dense":
-        h = apply_norm(p["ln2"], x, cfg)
-        h = apply_dense_ffn(p["ffn"], h, cfg)
-        if cfg.sandwich_norms:
-            h = apply_norm(p["ln2_post"], h, cfg)
-        x = x + h
-    elif spec.ffn == "moe":
-        h = apply_norm(p["ln2"], x, cfg)
-        h, _ = moe_mod.apply_moe(p["moe"], h, cfg)
-        if cfg.sandwich_norms:
-            h = apply_norm(p["ln2_post"], h, cfg)
-        x = x + h
+        with jax.named_scope("cross_attn"):
+            cache["enc_kv"] = attn_mod.encode_cross_kv(p["cross"], enc_out, cfg)
+            h = apply_norm(p["ln_cross"], x, cfg)
+            h = attn_mod.apply_cross_attention(p["cross"], h, cache["enc_kv"], cfg)
+            x = x + h
+    x = _serve_ffn(p, x, cfg, spec)
     x = shard(x, "batch", "seq", "embed")
     return x, cache
 
@@ -227,50 +224,52 @@ def init_layer_cache(cfg: ModelConfig, spec: LayerSpec, batch: int, max_seq: int
 
 def decode_layer(p, x, cache, pos, cfg: ModelConfig, spec: LayerSpec, angles):
     new_cache = dict(cache)
-    if spec.attn in ("full", "window"):
-        h = apply_norm(p["ln1"], x, cfg)
-        h, new_cache["kv"] = attn_mod.decode_attention(
-            p["attn"], h, cache["kv"], pos, cfg, spec, angles
-        )
-        if cfg.sandwich_norms:
-            h = apply_norm(p["ln1_post"], h, cfg)
-        x = x + h
-    elif spec.attn == "mla":
-        h = apply_norm(p["ln1"], x, cfg)
-        h, new_cache["kv"] = mla_mod.decode_mla(p["attn"], h, cache["kv"], pos, cfg, spec, angles)
-        x = x + h
-    elif spec.attn == "mamba":
-        h = apply_norm(p["ln1"], x, cfg)
-        h, new_cache["ssm"] = ssm_mod.decode_mamba(p["mamba"], h, cache["ssm"], cfg)
-        x = x + h
-    elif spec.attn == "hybrid":
-        h = apply_norm(p["ln1"], x, cfg)
-        a, new_cache["kv"] = attn_mod.decode_attention(
-            p["attn"], h, cache["kv"], pos, cfg, spec, angles
-        )
-        m, new_cache["ssm"] = ssm_mod.decode_mamba(p["mamba"], h, cache["ssm"], cfg)
-        h = 0.5 * (
-            rmsnorm(p["branch_norm_a"], a, cfg.norm_eps)
-            + rmsnorm(p["branch_norm_m"], m, cfg.norm_eps)
-        )
-        x = x + h
+    if spec.attn != "none":
+        with jax.named_scope(MIX_SCOPE[spec.attn]):
+            h = apply_norm(p["ln1"], x, cfg)
+            if spec.attn in ("full", "window"):
+                h, new_cache["kv"] = attn_mod.decode_attention(
+                    p["attn"], h, cache["kv"], pos, cfg, spec, angles
+                )
+                if cfg.sandwich_norms:
+                    h = apply_norm(p["ln1_post"], h, cfg)
+            elif spec.attn == "mla":
+                h, new_cache["kv"] = mla_mod.decode_mla(
+                    p["attn"], h, cache["kv"], pos, cfg, spec, angles
+                )
+            elif spec.attn == "mamba":
+                h, new_cache["ssm"] = ssm_mod.decode_mamba(p["mamba"], h, cache["ssm"], cfg)
+            else:  # hybrid
+                a, new_cache["kv"] = attn_mod.decode_attention(
+                    p["attn"], h, cache["kv"], pos, cfg, spec, angles
+                )
+                m, new_cache["ssm"] = ssm_mod.decode_mamba(p["mamba"], h, cache["ssm"], cfg)
+                h = 0.5 * (
+                    rmsnorm(p["branch_norm_a"], a, cfg.norm_eps)
+                    + rmsnorm(p["branch_norm_m"], m, cfg.norm_eps)
+                )
+            x = x + h
     if spec.cross_attn:
-        h = apply_norm(p["ln_cross"], x, cfg)
-        h = attn_mod.apply_cross_attention(p["cross"], h, cache["enc_kv"], cfg)
-        x = x + h
-    if spec.ffn == "dense":
+        with jax.named_scope("cross_attn"):
+            h = apply_norm(p["ln_cross"], x, cfg)
+            h = attn_mod.apply_cross_attention(p["cross"], h, cache["enc_kv"], cfg)
+            x = x + h
+    return _serve_ffn(p, x, cfg, spec), new_cache
+
+
+def _serve_ffn(p, x, cfg: ModelConfig, spec: LayerSpec):
+    """The feed-forward block of prefill and decode (no aux loss, no remat names)."""
+    if spec.ffn == "none":
+        return x
+    with jax.named_scope("moe" if spec.ffn == "moe" else "ffn"):
         h = apply_norm(p["ln2"], x, cfg)
-        h = apply_dense_ffn(p["ffn"], h, cfg)
+        if spec.ffn == "dense":
+            h = apply_dense_ffn(p["ffn"], h, cfg)
+        else:
+            h, _ = moe_mod.apply_moe(p["moe"], h, cfg)
         if cfg.sandwich_norms:
             h = apply_norm(p["ln2_post"], h, cfg)
-        x = x + h
-    elif spec.ffn == "moe":
-        h = apply_norm(p["ln2"], x, cfg)
-        h, _ = moe_mod.apply_moe(p["moe"], h, cfg)
-        if cfg.sandwich_norms:
-            h = apply_norm(p["ln2_post"], h, cfg)
-        x = x + h
-    return x, new_cache
+        return x + h
 
 
 # --------------------------------------------------------------- program
@@ -456,32 +455,38 @@ class Model:
     def prefill(self, params, batch, max_seq: int | None = None):
         """Forward the prompt, return (last-position logits, filled cache)."""
         cfg = self.cfg
-        x, positions = self._embed_inputs(params, batch)
-        x = shard(x, "batch", "seq", "embed")
+        with jax.named_scope("embed"):
+            x, positions = self._embed_inputs(params, batch)
+            x = shard(x, "batch", "seq", "embed")
         angles = self._angles(positions)
         S = x.shape[1]
         max_seq = max_seq or S
-        x, cache = prefill_program(
-            params["blocks"], x, cfg, cfg.program, angles, max_seq
-        )
-        x = apply_norm(params["final_norm"], x, cfg)
-        logits = lm_logits(params["embed"], x[:, -1:], cfg)
+        with jax.named_scope("layers"):
+            x, cache = prefill_program(
+                params["blocks"], x, cfg, cfg.program, angles, max_seq
+            )
+        with jax.named_scope("head"):
+            x = apply_norm(params["final_norm"], x, cfg)
+            logits = lm_logits(params["embed"], x[:, -1:], cfg)
         return logits, cache
 
     def decode_step(self, params, cache, tokens, pos):
         """tokens [B,1] int32, pos scalar int32 -> (logits [B,1,V], cache)."""
         cfg = self.cfg
-        x = embed_tokens(params["embed"], tokens, cfg)
-        if cfg.scale_embed:
-            x = x * jnp.asarray(cfg.d_model**0.5, x.dtype)
+        with jax.named_scope("embed"):
+            x = embed_tokens(params["embed"], tokens, cfg)
+            if cfg.scale_embed:
+                x = x * jnp.asarray(cfg.d_model**0.5, x.dtype)
         B = x.shape[0]
         positions = jnp.broadcast_to(pos[None, None], (B, 1))
         if cfg.mrope_sections is not None:
             positions = jnp.broadcast_to(pos[None, None, None], (3, B, 1))
         angles = self._angles(positions)
-        x, new_cache = decode_program(
-            params["blocks"], cache, x, pos, cfg, cfg.program, angles
-        )
-        x = apply_norm(params["final_norm"], x, cfg)
-        logits = lm_logits(params["embed"], x, cfg)
+        with jax.named_scope("layers"):
+            x, new_cache = decode_program(
+                params["blocks"], cache, x, pos, cfg, cfg.program, angles
+            )
+        with jax.named_scope("head"):
+            x = apply_norm(params["final_norm"], x, cfg)
+            logits = lm_logits(params["embed"], x, cfg)
         return logits, new_cache
