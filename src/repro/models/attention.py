@@ -5,7 +5,11 @@ Three execution paths share exact semantics:
   * chunked — lax.scan over query blocks with online masking; bounds live
               memory to O(q_block * S) and, for window layers, slices K/V to
               the reachable window only (true FLOP reduction, not just mask).
-  * decode  — single-token step against a full or ring KV cache.
+  * decode  — single-token step against a full or ring KV cache.  The step
+              writes the new token's k and v, one slot, in place into the
+              cache that the layer loop carries (``layers.CacheView``), then
+              reads the layer's cache back for attention; it never copies
+              the cache whole.
 
 Keys are cached post-RoPE.  Ring caches (window layers) store absolute slot
 positions implicitly: slot j at decode position p was written at
@@ -22,7 +26,7 @@ import numpy as np
 
 from repro.configs.base import LayerSpec, ModelConfig
 from repro.distributed.sharding import shard_pick
-from .layers import rmsnorm
+from .layers import CacheView, rmsnorm
 from .rope import apply_rope
 
 # Sequences at or above this length use the chunked path in train/prefill.
@@ -263,21 +267,24 @@ def init_kv_cache(cfg: ModelConfig, spec: LayerSpec, batch: int, max_seq: int, d
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
-def decode_attention(p, x, cache, pos, cfg: ModelConfig, spec: LayerSpec, angles):
-    """One-token decode. x [B,1,D]; pos scalar int32; returns (out, new_cache)."""
+def decode_attention(p, x, cache: CacheView, pos, cfg: ModelConfig, spec: LayerSpec, angles):
+    """One-token decode. x [B,1,D]; pos scalar int32; ``cache`` views the
+    layer's ``k`` and ``v`` [B,W,KV,hd]. Returns (out, cache) with the token's
+    k and v written in place at its slot."""
     q, k, v = _project_qkv(p, x, cfg, angles)
 
     # Unified ring-buffer update: full caches are rings of size max_seq, so
     # slot == pos and the validity mask reduces to idx <= pos; window caches
     # wrap and the mask keeps exactly the last `window` positions.
-    W = cache["k"].shape[1]
+    W = cache.shape("k")[1]
     slot = pos % W
     with jax.named_scope("kv_write"):
-        new_k = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, slot, axis=1)
-        new_v = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, slot, axis=1)
+        at = (0, slot, 0, 0)
+        cache = cache.write("k", k, at).write("v", v, at)
+        ck, cv = cache.read("k"), cache.read("v")
 
     idx = jnp.arange(W)
     written_at = pos - jnp.mod(pos - idx, W)  # last write position of slot idx
     mask = written_at >= 0
-    out = _sdpa(q, new_k, new_v, mask[None, None, None, :], cfg)
-    return _project_out(p, out, x.dtype), {"k": new_k, "v": new_v}
+    out = _sdpa(q, ck, cv, mask[None, None, None, :], cfg)
+    return _project_out(p, out, x.dtype), cache

@@ -1,4 +1,4 @@
-"""Shared layer primitives: norms, FFNs, embeddings.
+"""Shared layer primitives: norms, FFNs, embeddings, the decode cache view.
 
 Everything is functional: ``init_*`` builds a param pytree, ``apply``-style
 functions consume it.  Params default to float32 masters; activations run in
@@ -6,6 +6,9 @@ functions consume it.  Params default to float32 masters; activations run in
 """
 
 from __future__ import annotations
+
+from dataclasses import dataclass
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -153,3 +156,36 @@ def chunked_softmax_xent(
     body = jax.checkpoint(body)
     (tot, cnt), _ = jax.lax.scan(body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32)), (xs, ls))
     return tot / jnp.maximum(cnt, 1).astype(jnp.float32)
+
+
+# ------------------------------------------------------------ decode cache
+@dataclass(frozen=True)
+class CacheView:
+    """One layer's entry of the decode cache that the layer loop carries.
+
+    ``tree`` maps names to the buffers the loop carries: the layer's own
+    entry, or, with ``index``, the entries of a segment's repeats stacked on
+    a leading axis, the layer's at ``index``. ``read`` takes the layer's entry
+    out; ``write`` puts an update in place at ``at`` along the entry's axes,
+    so a decode step writes only what it changed: one token's slot of a KV
+    cache, a recurrent state whole.
+    """
+
+    tree: Any
+    index: Any = None
+
+    def shape(self, name) -> tuple[int, ...]:
+        s = self.tree[name].shape
+        return s if self.index is None else s[1:]
+
+    def read(self, name):
+        a = self.tree[name]
+        if self.index is None:
+            return a
+        return jax.lax.dynamic_index_in_dim(a, self.index, keepdims=False)
+
+    def write(self, name, update, at) -> "CacheView":
+        if self.index is not None:
+            update, at = update[None], (self.index, *at)
+        a = jax.lax.dynamic_update_slice(self.tree[name], update, tuple(at))
+        return CacheView({**self.tree, name: a}, self.index)

@@ -16,7 +16,7 @@ import numpy as np
 
 from repro.configs.base import LayerSpec, ModelConfig
 from repro.distributed.sharding import shard_pick
-from .layers import rmsnorm
+from .layers import CacheView, rmsnorm
 from .rope import apply_rope
 
 
@@ -107,13 +107,15 @@ def init_mla_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype):
     }
 
 
-def decode_mla(p, x, cache, pos, cfg: ModelConfig, spec: LayerSpec, angles):
-    """Absorbed one-token decode against the compressed cache."""
+def decode_mla(p, x, cache: CacheView, pos, cfg: ModelConfig, spec: LayerSpec, angles):
+    """Absorbed one-token decode against the compressed cache; ``cache`` views
+    the layer's ``c_kv`` and ``k_rope``, written in place at slot ``pos``."""
     dt = x.dtype
     q_nope, q_rope = _project_q(p, x, cfg, angles)          # [B,1,H,*]
     c_new, kr_new = _compress_kv(p, x, cfg, angles)         # [B,1,lora], [B,1,rope]
-    c_kv = jax.lax.dynamic_update_slice_in_dim(cache["c_kv"], c_new, pos, axis=1)
-    k_rope = jax.lax.dynamic_update_slice_in_dim(cache["k_rope"], kr_new, pos, axis=1)
+    with jax.named_scope("kv_write"):
+        cache = cache.write("c_kv", c_new, (0, pos, 0)).write("k_rope", kr_new, (0, pos, 0))
+        c_kv, k_rope = cache.read("c_kv"), cache.read("k_rope")
 
     # Absorb W_uk into q: score_nope = (q_nope W_uk^T) . c_kv
     q_abs = jnp.einsum("bqhk,lhk->bqhl", q_nope, p["w_uk"].astype(dt))  # [B,1,H,lora]
@@ -127,4 +129,4 @@ def decode_mla(p, x, cache, pos, cfg: ModelConfig, spec: LayerSpec, angles):
     ctx = jnp.einsum("bhqs,bsl->bqhl", w, c_kv)             # [B,1,H,lora]
     out = jnp.einsum("bqhl,lhk->bqhk", ctx, p["w_uv"].astype(dt))  # absorb W_uv
     out = jnp.einsum("bqhk,hkd->bqd", out, p["wo"].astype(dt))
-    return out, {"c_kv": c_kv, "k_rope": k_rope}
+    return out, cache

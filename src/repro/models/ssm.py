@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from .layers import rmsnorm
+from .layers import CacheView, rmsnorm
 
 
 def _dims(cfg: ModelConfig):
@@ -180,15 +180,16 @@ def init_mamba_cache(cfg: ModelConfig, batch: int, dtype):
     }
 
 
-def decode_mamba(p, x_in, cache, cfg: ModelConfig):
-    """One-token recurrent step. x_in [B,1,D] -> ([B,1,D], new_cache)."""
+def decode_mamba(p, x_in, cache: CacheView, cfg: ModelConfig):
+    """One-token recurrent step. x_in [B,1,D] -> ([B,1,D], cache); ``cache``
+    views the layer's ``state`` and ``conv`` tail, both written whole."""
     dt_ = x_in.dtype
     di, H, P, N, G, conv_dim = _dims(cfg)
     zxbcdt = jnp.einsum("bsd,dk->bsk", x_in, p["in_proj"].astype(dt_))
     z, xBC_new, dt_raw = _split_proj(zxbcdt, cfg)
 
     # conv over [cached K-1 tail, new column]
-    window = jnp.concatenate([cache["conv"], xBC_new], axis=1)     # [B,K,conv]
+    window = jnp.concatenate([cache.read("conv"), xBC_new], axis=1)     # [B,K,conv]
     conv_out = (window * p["conv_w"].astype(dt_)[None]).sum(1, keepdims=True)
     xBC = jax.nn.silu(conv_out + p["conv_b"].astype(dt_))
     new_conv = window[:, 1:]
@@ -202,7 +203,7 @@ def decode_mamba(p, x_in, cache, cfg: ModelConfig):
     A = -jnp.exp(p["A_log"])
     dA = jnp.exp(dt * A)                                            # [B,H]
 
-    state = cache["state"] * dA[..., None, None] + jnp.einsum(
+    state = cache.read("state") * dA[..., None, None] + jnp.einsum(
         "bhp,bhn,bh->bhpn", x.astype(jnp.float32), Bh.astype(jnp.float32), dt
     )
     y = jnp.einsum("bhpn,bhn->bhp", state, Ch.astype(jnp.float32)).astype(dt_)
@@ -210,4 +211,5 @@ def decode_mamba(p, x_in, cache, cfg: ModelConfig):
     y = y.reshape(x_in.shape[0], 1, di)
     y = rmsnorm(p["norm"], y * jax.nn.silu(z), cfg.norm_eps)
     out = jnp.einsum("bsk,kd->bsd", y, p["out_proj"].astype(dt_))
-    return out, {"state": state, "conv": new_conv}
+    cache = cache.write("state", state, (0, 0, 0, 0)).write("conv", new_conv, (0, 0, 0))
+    return out, cache

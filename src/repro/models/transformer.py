@@ -30,6 +30,7 @@ from . import mla as mla_mod
 from . import moe as moe_mod
 from . import ssm as ssm_mod
 from .layers import (
+    CacheView,
     apply_dense_ffn,
     apply_norm,
     chunked_softmax_xent,
@@ -222,28 +223,35 @@ def init_layer_cache(cfg: ModelConfig, spec: LayerSpec, batch: int, max_seq: int
     return c
 
 
-def decode_layer(p, x, cache, pos, cfg: ModelConfig, spec: LayerSpec, angles):
+def decode_layer(p, x, cache, index, pos, cfg: ModelConfig, spec: LayerSpec, angles):
+    """One token through one layer. ``cache`` is the layer's entry, or with
+    ``index`` the segment's stacked entries, the layer's at ``index``; each
+    block writes what it changed in place (``CacheView``). Returns (x, cache)."""
     new_cache = dict(cache)
     if spec.attn != "none":
         with jax.named_scope(MIX_SCOPE[spec.attn]):
             h = apply_norm(p["ln1"], x, cfg)
             if spec.attn in ("full", "window"):
-                h, new_cache["kv"] = attn_mod.decode_attention(
-                    p["attn"], h, cache["kv"], pos, cfg, spec, angles
+                h, kv = attn_mod.decode_attention(
+                    p["attn"], h, CacheView(cache["kv"], index), pos, cfg, spec, angles
                 )
+                new_cache["kv"] = kv.tree
                 if cfg.sandwich_norms:
                     h = apply_norm(p["ln1_post"], h, cfg)
             elif spec.attn == "mla":
-                h, new_cache["kv"] = mla_mod.decode_mla(
-                    p["attn"], h, cache["kv"], pos, cfg, spec, angles
+                h, kv = mla_mod.decode_mla(
+                    p["attn"], h, CacheView(cache["kv"], index), pos, cfg, spec, angles
                 )
+                new_cache["kv"] = kv.tree
             elif spec.attn == "mamba":
-                h, new_cache["ssm"] = ssm_mod.decode_mamba(p["mamba"], h, cache["ssm"], cfg)
+                h, ssm = ssm_mod.decode_mamba(p["mamba"], h, CacheView(cache["ssm"], index), cfg)
+                new_cache["ssm"] = ssm.tree
             else:  # hybrid
-                a, new_cache["kv"] = attn_mod.decode_attention(
-                    p["attn"], h, cache["kv"], pos, cfg, spec, angles
+                a, kv = attn_mod.decode_attention(
+                    p["attn"], h, CacheView(cache["kv"], index), pos, cfg, spec, angles
                 )
-                m, new_cache["ssm"] = ssm_mod.decode_mamba(p["mamba"], h, cache["ssm"], cfg)
+                m, ssm = ssm_mod.decode_mamba(p["mamba"], h, CacheView(cache["ssm"], index), cfg)
+                new_cache["kv"], new_cache["ssm"] = kv.tree, ssm.tree
                 h = 0.5 * (
                     rmsnorm(p["branch_norm_a"], a, cfg.norm_eps)
                     + rmsnorm(p["branch_norm_m"], m, cfg.norm_eps)
@@ -251,8 +259,9 @@ def decode_layer(p, x, cache, pos, cfg: ModelConfig, spec: LayerSpec, angles):
             x = x + h
     if spec.cross_attn:
         with jax.named_scope("cross_attn"):
+            enc = CacheView(cache["enc_kv"], index)
             h = apply_norm(p["ln_cross"], x, cfg)
-            h = attn_mod.apply_cross_attention(p["cross"], h, cache["enc_kv"], cfg)
+            h = attn_mod.apply_cross_attention(p["cross"], h, (enc.read(0), enc.read(1)), cfg)
             x = x + h
     return _serve_ffn(p, x, cfg, spec), new_cache
 
@@ -355,28 +364,40 @@ def init_program_cache(cfg, program, batch, max_seq, dtype):
 
 
 def decode_program(segs, caches, x, pos, cfg, program, angles):
+    """One token through every segment. Returns (x, caches).
+
+    A segment of repeats carries its stacked cache in the layer loop's carry
+    and scans over (layer index, params): each layer reads its own entry by
+    that index and writes back only what it changed, one slot-sized
+    ``dynamic_update_slice`` in place into the carried buffer (one token's
+    k and v, MLA latent or ring slot; a recurrent state whole). A segment of
+    one layer writes its entry the same way without the index. The cache is
+    never copied whole, so a donated cache is updated in place.
+    """
     new_caches = []
     for (unit, reps), seg_params, seg_cache in zip(program, segs, caches):
 
-        def unit_fn(params, cache, x):
-            new_cache = {}
+        def unit_fn(params, cache, x, index):
+            cache = dict(cache)
             for i, spec in enumerate(unit):
-                x, new_cache[f"l{i}"] = decode_layer(
-                    params[f"l{i}"], x, cache[f"l{i}"], pos, cfg, spec, angles
+                x, cache[f"l{i}"] = decode_layer(
+                    params[f"l{i}"], x, cache[f"l{i}"], index, pos, cfg, spec, angles
                 )
-            return x, new_cache
+            return x, cache
 
         if reps > 1:
 
-            def body(x, pc):
-                params, cache = pc
-                x, nc = unit_fn(params, cache, x)
-                return x, nc
+            def body(carry, inp):
+                x, cache = carry
+                index, params = inp
+                return unit_fn(params, cache, x, index), None
 
-            x, nc = jax.lax.scan(body, x, (seg_params, seg_cache))
+            (x, seg_cache), _ = jax.lax.scan(
+                body, (x, seg_cache), (jnp.arange(reps), seg_params)
+            )
         else:
-            x, nc = unit_fn(seg_params, seg_cache, x)
-        new_caches.append(nc)
+            x, seg_cache = unit_fn(seg_params, seg_cache, x, None)
+        new_caches.append(seg_cache)
     return x, new_caches
 
 
