@@ -1,8 +1,10 @@
 """Architecture registry: ``get_config(arch)`` / ``get_smoke_config(arch)``.
 
-All 10 assigned architectures are selectable by id (``--arch <id>``); the
-paper's own CNN benchmark families live in models/cnn.py and are addressed by
-name ("vgg16", "resnet50", ...) in the benchmarks.
+All 10 assigned architectures, and the expert-parallel share of
+deepseek-v2-lite-16b that one chip holds, are selectable by id
+(``--arch <id>``); the paper's own CNN benchmark families live in
+models/cnn.py and are addressed by name ("vgg16", "resnet50", ...) in the
+benchmarks.
 """
 
 from __future__ import annotations
@@ -18,6 +20,7 @@ ARCHS: dict[str, str] = {
     "starcoder2-7b": "starcoder2_7b",
     "gemma2-9b": "gemma2_9b",
     "deepseek-v2-lite-16b": "deepseek_v2_lite_16b",
+    "deepseek-v2-lite-16b-ep8": "deepseek_v2_lite_16b_ep8",
     "llama4-maverick-400b-a17b": "llama4_maverick_400b_a17b",
     "qwen2-vl-7b": "qwen2_vl_7b",
     "mamba2-370m": "mamba2_370m",

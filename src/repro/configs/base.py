@@ -37,6 +37,20 @@ Segment = tuple[tuple[LayerSpec, ...], int]
 
 
 @dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling (arXiv:2309.00071), as DeepSeek-V2 publishes it under
+    ``rope_scaling``: the rope frequencies stretched by ``factor`` beyond
+    ``original_max_position``, and the attention scaled by mscale."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float
+    beta_slow: float
+    mscale: float
+    mscale_all_dim: float
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str                   # dense | moe | vlm | ssm | audio | hybrid
@@ -56,6 +70,7 @@ class ModelConfig:
     rope_theta: float = 10_000.0
     mrope_sections: tuple[int, int, int] | None = None  # M-RoPE (qwen2-vl)
     attn_scale: float | None = None                     # override 1/sqrt(hd)
+    rope_scaling: YarnScaling | None = None
 
     # ---- MLA (deepseek) ----
     kv_lora_rank: int = 0
@@ -65,12 +80,17 @@ class ModelConfig:
     v_head_dim: int = 0
 
     # ---- MoE ----
-    num_experts: int = 0
+    num_experts: int = 0          # the router's outputs: every routed expert
     num_shared_experts: int = 0
     top_k: int = 0
     moe_d_ff: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # the shard_map expert-parallel path only
     router_type: str = "softmax"  # softmax | sigmoid (llama4 top-1)
+    norm_topk_prob: bool = True   # renormalize the top-k softmax gates to sum 1
+    # The routed experts this chip holds: ids first_expert .. first_expert +
+    # experts_held - 1 of the num_experts routed over (0: all of them).
+    first_expert: int = 0
+    experts_held: int = 0
 
     # ---- FFN ----
     ffn_act: str = "swiglu"       # swiglu | gelu
@@ -107,6 +127,16 @@ class ModelConfig:
             raise ValueError(
                 f"{self.name}: program covers {n} layers, config says {self.num_layers}"
             )
+        if self.first_expert + self.num_held_experts > self.num_experts:
+            last = self.first_expert + self.num_held_experts - 1
+            raise ValueError(
+                f"{self.name}: experts {self.first_expert}..{last} held, "
+                f"the router has {self.num_experts}"
+            )
+
+    @property
+    def num_held_experts(self) -> int:
+        return self.experts_held or self.num_experts
 
     @property
     def d_inner(self) -> int:  # mamba inner width

@@ -2,11 +2,13 @@
 moe_d_ff=1408, vocab=102400, 64 routed experts top-6 + 2 shared.
 
 MLA dims per arXiv:2405.04434 (lite): qk_nope=128, qk_rope=64, v_head=128,
-no q-LoRA; first layer is dense (d_ff=10944), layers 1..26 are MoE.
-[hf-verified]
+no q-LoRA; first layer is dense (d_ff=10944), layers 1..26 are MoE. The
+router's softmax gates of the top 6 are not renormalized (norm_topk_prob
+false, routed_scaling_factor 1); rope is YaRN, factor 40 over 4096
+positions. [hf-verified]
 """
 
-from .base import LayerSpec, ModelConfig
+from .base import LayerSpec, ModelConfig, YarnScaling
 
 _DENSE = LayerSpec(attn="mla", ffn="dense")
 _MOE = LayerSpec(attn="mla", ffn="moe")
@@ -32,9 +34,11 @@ def config() -> ModelConfig:
         num_shared_experts=2,
         top_k=6,
         moe_d_ff=1408,
-        capacity_factor=1.5,
         router_type="softmax",
+        norm_topk_prob=False,
         rope_theta=10_000.0,
+        rope_scaling=YarnScaling(factor=40.0, original_max_position=4096, beta_fast=32.0,
+                                 beta_slow=1.0, mscale=0.707, mscale_all_dim=0.707),
         tie_embeddings=False,
     )
 
@@ -61,6 +65,11 @@ def smoke_config() -> ModelConfig:
         num_shared_experts=2,
         top_k=2,
         moe_d_ff=32,
-        capacity_factor=1.5,
+        norm_topk_prob=False,
+        # Stretched beyond 16 positions, so that the smoke tests' sequences
+        # reach the scaled frequencies.
+        rope_scaling=YarnScaling(factor=4.0, original_max_position=16, beta_fast=32.0,
+                                 beta_slow=1.0, mscale=0.707, mscale_all_dim=0.707),
+        tie_embeddings=False,
         dtype="float32",
     )

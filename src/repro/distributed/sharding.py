@@ -40,11 +40,6 @@ DEFAULT_RULES: dict[str, object] = {
     # embarrassingly parallel over batch, so when enough batch exists this
     # beats both head sharding (no output all-reduce) and seq sharding
     "batch_full": ("pod", "data", "model"),
-    # MoE dispatch-row dim (token-expert pairs). Unmapped by default (no-op);
-    # the "moe_local" perf profile maps it to ("pod", "data") so gathers and
-    # scatters around the sort-based dispatch stay batch-local instead of
-    # letting GSPMD replicate the token table per device.
-    "tokens": None,
     # batch over (pod, data) REGARDLESS of profile: the chunked-CE logits must
     # keep vocab on "model" (otherwise the batch_full profile forces a full
     # embedding-table all-gather per CE chunk — 75 GB/dev/step on gemma3).
@@ -162,22 +157,3 @@ def named_sharding(mesh: Mesh, *axes: str | None, rules: dict | None = None) -> 
 
 def batch_axes(mesh: Mesh) -> tuple[str, ...]:
     return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-
-
-def token_group_count() -> int:
-    """Shard count the "tokens" logical axis maps to (1 = unmapped/no mesh).
-
-    models/moe.py groups its dispatch by this count so sort/scatter indices
-    stay shard-local (see the moe_local profile)."""
-    if _CTX.mesh is None or _CTX.mesh.empty:
-        return 1
-    rules = _CTX.rules or DEFAULT_RULES
-    m = rules.get("tokens")
-    if not m:
-        return 1
-    axes = m if isinstance(m, tuple) else (m,)
-    n = 1
-    for a in axes:
-        if a in _CTX.mesh.axis_names:
-            n *= _CTX.mesh.shape[a]
-    return n

@@ -120,34 +120,19 @@ PROFILES: dict[str, dict] = {
     # batch fully sharded over the whole mesh for activations: turns the
     # Megatron-style per-layer activation all-reduces into tiny b-local ones
     "fsdp_act": {"rules": {"batch": ("pod", "data", "model")}},
-    # keep MoE dispatch-row intermediates batch-sharded (see models/moe.py)
-    "moe_local": {"rules": {"tokens": ("pod", "data")}},
-    "fsdp_moe": {"rules": {"batch": ("pod", "data", "model"),
-                           "tokens": ("pod", "data", "model")}},
     # the paper's technique on TPU: offload saved block inputs to pinned_host
     "offload": {"offload_names": ["block_in"]},
     # gradient accumulation: 8 microbatches
     "accum8": {"accum_steps": 8},
     "fsdp_accum8": {"rules": {"batch": ("pod", "data", "model")}, "accum_steps": 8},
-    "fsdp_moe_accum8": {"rules": {"batch": ("pod", "data", "model"),
-                                  "tokens": ("pod", "data", "model")},
-                        "accum_steps": 8},
     "fsdp_offload": {"rules": {"batch": ("pod", "data", "model")},
                      "offload_names": ["block_in"]},
     # flash-style chunked attention even at 4k: bounds the per-layer scores
     # working set to q_block x S instead of S x S
     "fsdp_chunked": {"rules": {"batch": ("pod", "data", "model")},
                      "chunked_attn": True},
-    "fsdp_moe_chunked": {"rules": {"batch": ("pod", "data", "model"),
-                                   "tokens": ("pod", "data", "model")},
-                         "chunked_attn": True},
-    "moe_local_accum8": {"rules": {"tokens": ("pod", "data")}, "accum_steps": 8},
-    "moe_local_fsdp": {"rules": {"batch": ("pod", "data", "model"),
-                                 "tokens": ("pod", "data")}},
-    # B6: expert weights sharded over model only (no FSDP F-dim over data):
-    # removes the partial-sum all-reduce of [E,C,D] inside every MoE layer
-    "moe_local_accum8_nofsdp": {"rules": {"tokens": ("pod", "data")},
-                                "accum_steps": 8, "fsdp_params": False},
+    # B6: expert weights sharded over model only (no FSDP F-dim over data)
+    "accum8_nofsdp": {"accum_steps": 8, "fsdp_params": False},
     # B8: hand-written EP all-to-all under shard_map (models/moe.py)
     "moe_shardmap": {"rules": {"moe_impl": "shard_map"}, "fsdp_params": False},
     "moe_shardmap_accum8": {"rules": {"moe_impl": "shard_map"},

@@ -1,18 +1,20 @@
-"""Mixture-of-Experts FFN with sort-based capacity dispatch (EP-friendly).
+"""Mixture-of-Experts FFN: dropless grouped dispatch over the experts held.
 
-Dispatch is gather/scatter (no [T, E, C] one-hot matmul): token-expert pairs
-are sorted by expert, ranked within their expert group, and dropped beyond
-capacity C = ceil(T * top_k / E * capacity_factor).  FLOPs are therefore the
-honest E*C*(3*2*d*f) expert compute — crucial for roofline fidelity (a dense
-one-hot dispatch would inflate llama4's compute 128x).
+A layer holds routed experts ``first_expert ..`` (``cfg.num_held_experts``
+of the ``num_experts`` its router routes over: one chip's share under
+expert parallelism, or all of them) and computes their part of the result:
+every token is routed over all the router's outputs, the token-expert pairs
+whose expert is held are sorted by expert, and each held expert's SwiGLU
+runs as one grouped product (``lax.ragged_dot``) over its rows. No pair is
+dropped and no capacity is set, so the operations follow the pairs that
+land on the held experts. The shared experts are added once.
 
-Expert weights are [E, d, f]; sharding E over the `model` mesh axis gives
-expert parallelism (llama4: 128/16 = 8 experts per shard); the scatter/gather
-lowers to all-to-all under GSPMD.
+Expert weights are [E_held, d, f]. ``apply_moe_shardmap`` is the explicit
+expert-parallel exchange over a mesh (capacity-based, all experts held).
 
-Routers: softmax top-k with renormalization (deepseek) or sigmoid top-1
-(llama4).  An auxiliary load-balance loss (Switch-style) is returned for
-training.
+Routers: softmax top-k, renormalized where ``norm_topk_prob`` (deepseek-v2
+publishes it false), or sigmoid top-1 (llama4).  An auxiliary load-balance
+loss (Switch-style) is returned for training.
 """
 
 from __future__ import annotations
@@ -22,19 +24,19 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.distributed.sharding import shard
 from .layers import init_dense_ffn, apply_dense_ffn
 
 
 def init_moe(key, cfg: ModelConfig):
     d, f, E = cfg.d_model, cfg.moe_d_ff or cfg.d_ff, cfg.num_experts
+    held = cfg.num_held_experts
     ks = jax.random.split(key, 5)
     s_in, s_out = 1.0 / np.sqrt(d), 1.0 / np.sqrt(f)
     p = {
         "router": jax.random.normal(ks[0], (d, E), jnp.float32) * s_in,
-        "w_gate": jax.random.normal(ks[1], (E, d, f), jnp.float32) * s_in,
-        "w_up": jax.random.normal(ks[2], (E, d, f), jnp.float32) * s_in,
-        "w_down": jax.random.normal(ks[3], (E, f, d), jnp.float32) * s_out,
+        "w_gate": jax.random.normal(ks[1], (held, d, f), jnp.float32) * s_in,
+        "w_up": jax.random.normal(ks[2], (held, d, f), jnp.float32) * s_in,
+        "w_down": jax.random.normal(ks[3], (held, f, d), jnp.float32) * s_out,
     }
     if cfg.num_shared_experts:
         shared_f = f * cfg.num_shared_experts
@@ -42,23 +44,27 @@ def init_moe(key, cfg: ModelConfig):
     return p
 
 
-def _route(p, xt, cfg: ModelConfig):
-    """xt [T, D] -> (gates [T,k], idx [T,k], aux_loss scalar)."""
-    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32), p["router"])
-    k, E = cfg.top_k, cfg.num_experts
+def _route(router, xt, cfg: ModelConfig):
+    """xt [T, D] -> (logits [T,E], probs [T,E], gates [T,k], idx [T,k]), in
+    float32 over all the router's outputs."""
+    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32), router)
     if cfg.router_type == "sigmoid":
         probs = jax.nn.sigmoid(logits)
-        gates, idx = jax.lax.top_k(probs, k)
+        gates, idx = jax.lax.top_k(probs, cfg.top_k)
     else:
         probs = jax.nn.softmax(logits, axis=-1)
-        gates, idx = jax.lax.top_k(probs, k)
-        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
-    # Switch-style load-balance aux: E * sum_e f_e * p_e.
-    onehot = jax.nn.one_hot(idx, E, dtype=jnp.float32).sum(1)        # [T, E]
-    f_e = onehot.mean(0)
-    p_e = probs.mean(0) if cfg.router_type != "sigmoid" else jax.nn.softmax(logits, -1).mean(0)
-    aux = E * jnp.sum(f_e * p_e)
-    return gates, idx, aux
+        gates, idx = jax.lax.top_k(probs, cfg.top_k)
+        if cfg.norm_topk_prob:
+            gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    return logits, probs, gates, idx
+
+
+def _aux_loss(logits, probs, idx, cfg: ModelConfig):
+    """Switch-style load-balance aux: E * sum_e f_e * p_e."""
+    E = cfg.num_experts
+    f_e = jax.nn.one_hot(idx, E, dtype=jnp.float32).sum(1).mean(0)
+    p_e = jax.nn.softmax(logits, -1).mean(0) if cfg.router_type == "sigmoid" else probs.mean(0)
+    return E * jnp.sum(f_e * p_e)
 
 
 def apply_moe_shardmap(p, x, cfg: ModelConfig):
@@ -113,14 +119,7 @@ def apply_moe_shardmap(p, x, cfg: ModelConfig):
             wu = jax.lax.all_gather(wu.astype(dt), "data", axis=2, tiled=True)
             wd = jax.lax.all_gather(wd.astype(dt), "data", axis=1, tiled=True)
 
-        logits = jnp.einsum("td,de->te", xt.astype(jnp.float32), router)
-        if cfg.router_type == "sigmoid":
-            probs = jax.nn.sigmoid(logits)
-            gates, idx = jax.lax.top_k(probs, k)
-        else:
-            probs = jax.nn.softmax(logits, axis=-1)
-            gates, idx = jax.lax.top_k(probs, k)
-            gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+        _, _, gates, idx = _route(router, xt, cfg)
 
         flat_e = idx.reshape(-1)
         order = jnp.argsort(flat_e, stable=True)
@@ -175,19 +174,9 @@ def apply_moe_shardmap(p, x, cfg: ModelConfig):
 
 
 def apply_moe(p, x, cfg: ModelConfig):
-    """x [B, S, D] -> (y [B, S, D], aux_loss).
-
-    Dispatch is *grouped*: tokens are split into G groups matching the batch
-    sharding (G = token_group_count(); 1 on a single device / baseline
-    profile).  Sorting, capacity ranking, scatter and gather all use
-    group-local indices, so under the "moe_local" profile every index
-    operation is shard-local — no cross-device all-reduce of [T, D] scatter
-    partials (the dominant collective of the naive global dispatch; see
-    EXPERIMENTS.md §Perf cell B).  Capacity is per group (C/G each), which
-    slightly raises drop variance vs a global capacity pool — recorded in
-    DESIGN.md.
-    """
-    from repro.distributed.sharding import _CTX, token_group_count
+    """x [B, S, D] -> (y [B, S, D], aux_loss): the held experts' part of the
+    routed result, dropless, plus the shared experts."""
+    from repro.distributed.sharding import _CTX
 
     rules = _CTX.rules or {}
     if rules.get("moe_impl") == "shard_map" and _CTX.mesh is not None and not _CTX.mesh.empty:
@@ -195,45 +184,32 @@ def apply_moe(p, x, cfg: ModelConfig):
 
     dt = x.dtype
     B, S, D = x.shape
-    T = B * S
-    k, E = cfg.top_k, cfg.num_experts
-    G = token_group_count()
-    if T % G:
-        G = 1
-    Tg = T // G
+    T, k, held = B * S, cfg.top_k, cfg.num_held_experts
     xt = x.reshape(T, D)
 
-    gates, idx, aux = _route(p, xt, cfg)
-    C = int(np.ceil(Tg * k / E * cfg.capacity_factor))
-    C = max(8, -(-C // 8) * 8)  # multiple of 8, >= 8
-
-    flat_e = idx.reshape(G, Tg * k)                           # [G, Tg*k]
-    order = jnp.argsort(flat_e, axis=-1, stable=True)
-    sorted_e = jnp.take_along_axis(flat_e, order, axis=-1)
-    counts = jax.nn.one_hot(flat_e, E, dtype=jnp.int32).sum(1)  # [G, E]
-    start = jnp.cumsum(counts, axis=-1) - counts
-    rank = jnp.arange(Tg * k)[None] - jnp.take_along_axis(start, sorted_e, axis=-1)
-    token_of = order // k                                     # group-local ids
-    g_idx = jnp.arange(G)[:, None] * jnp.ones((1, Tg * k), jnp.int32)
-
-    # Group x expert layout: buf [G, E, C, D] sharded (batch, experts) — each
-    # device scatters its own tokens into its own experts' rows; rank >= C
-    # drops (mode="drop").
-    xg = shard(xt.reshape(G, Tg, D), "tokens", None, None)
-    xin = jnp.take_along_axis(xg, token_of[..., None], axis=1)  # [G, Tg*k, D]
-    buf = shard(jnp.zeros((G, E, C, D), dt), "tokens", "experts", None, None)
-    buf = buf.at[g_idx, sorted_e, rank].set(xin, mode="drop")
-    g_ = jnp.einsum("gecd,edf->gecf", buf, p["w_gate"].astype(dt))
-    u = jnp.einsum("gecd,edf->gecf", buf, p["w_up"].astype(dt))
-    y = jnp.einsum("gecf,efd->gecd", jax.nn.silu(g_) * u, p["w_down"].astype(dt))
-    y = shard(y, "tokens", "experts", None, None)
-
-    gate_sorted = jnp.take_along_axis(gates.reshape(G, Tg * k), order, axis=-1)
-    rows = y.at[g_idx, sorted_e, rank].get(mode="fill", fill_value=0)  # [G,Tg*k,D]
-    contrib = rows * gate_sorted[..., None].astype(dt)
-    out = jnp.zeros((G, Tg, D), dt).at[g_idx, token_of].add(contrib)
-    out = shard(out, "tokens", None, None).reshape(T, D)
-
+    with jax.named_scope("route"):
+        logits, probs, gates, idx = _route(p["router"], xt, cfg)
+        aux = _aux_loss(logits, probs, idx, cfg)
+    with jax.named_scope("dispatch"):
+        # Each pair's held expert, or ``held`` where its expert lies elsewhere:
+        # sorted, the held experts' pairs come first, grouped by expert.
+        expert = idx.reshape(T * k) - cfg.first_expert
+        expert = jnp.where((expert >= 0) & (expert < held), expert, held)
+        order = jnp.argsort(expert, stable=True)
+        sizes = jnp.bincount(expert, length=held + 1)[:held].astype(jnp.int32)
+        token_of = order // k
+        rows = xt[token_of]                                   # [T*k, D]
+    with jax.named_scope("experts"):
+        # Rows past the held groups' sizes are computed by no group.
+        g = jax.lax.ragged_dot(rows, p["w_gate"].astype(dt), sizes)
+        u = jax.lax.ragged_dot(rows, p["w_up"].astype(dt), sizes)
+        y = jax.lax.ragged_dot(jax.nn.silu(g) * u, p["w_down"].astype(dt), sizes)
+    with jax.named_scope("combine"):
+        mine = expert[order] < held
+        gate = gates.reshape(T * k)[order].astype(dt)
+        contrib = jnp.where(mine[:, None], y * gate[:, None], 0)
+        out = jnp.zeros((T, D), dt).at[token_of].add(contrib)
     if cfg.num_shared_experts:
-        out = out + apply_dense_ffn(p["shared"], xt, cfg)
+        with jax.named_scope("shared"):
+            out = out + apply_dense_ffn(p["shared"], xt, cfg)
     return out.reshape(B, S, D), aux

@@ -401,6 +401,30 @@ def decode_program(segs, caches, x, pos, cfg, program, angles):
     return x, new_caches
 
 
+def _write_rows(cache, rows, start):
+    """``rows``, a group's entry of a cache leaf, into the batch's ``cache`` at
+    row ``start`` of its batch axis: the one axis where the two differ."""
+    axis = next(i for i, (a, b) in enumerate(zip(cache.shape, rows.shape)) if a != b)
+    return jax.lax.dynamic_update_slice_in_dim(cache, rows.astype(cache.dtype), start, axis=axis)
+
+
+# Prefill takes a batch's rows in groups whose widest feed-forward activation
+# stays within this many bytes in bfloat16: a dense layer's [tokens, d_ff]
+# intermediate, a MoE layer's [tokens * top_k, d_model] dispatched rows, or
+# at least the residual stream.
+PREFILL_GROUP_BYTES = 5 << 28
+
+
+def prefill_group_rows(cfg: ModelConfig, batch: int, seq: int) -> int:
+    """Rows of a prompt batch that prefill takes in one pass: the largest
+    divisor of ``batch`` whose group keeps within PREFILL_GROUP_BYTES (one row
+    at least)."""
+    width = max(cfg.d_model, cfg.d_ff, cfg.top_k * cfg.d_model if cfg.num_experts else 0)
+    fits = [r for r in range(1, batch + 1)
+            if batch % r == 0 and r * seq * width * 2 <= PREFILL_GROUP_BYTES]
+    return max(fits, default=1)
+
+
 # ------------------------------------------------------------------ model
 @dataclass
 class Model:
@@ -428,7 +452,7 @@ class Model:
         hd = cfg.qk_rope_head_dim if cfg.kv_lora_rank else cfg.head_dim
         if cfg.mrope_sections is not None:
             return mrope_angles(positions, hd, cfg.rope_theta, cfg.mrope_sections)
-        return rope_angles(positions, hd, cfg.rope_theta)
+        return rope_angles(positions, hd, cfg.rope_theta, cfg.rope_scaling)
 
     def _embed_inputs(self, params, batch):
         """tokens (+ VLM patch embeds) -> (x [B,S,D], positions)."""
@@ -474,7 +498,32 @@ class Model:
         )
 
     def prefill(self, params, batch, max_seq: int | None = None):
-        """Forward the prompt, return (last-position logits, filled cache)."""
+        """Forward the prompt, return (last-position logits, filled cache).
+
+        A batch too large for one pass (``prefill_group_rows``) goes through
+        in groups of rows, each group writing its rows of the cache in place
+        into one cache of the whole batch."""
+        cfg = self.cfg
+        B, S = batch["tokens"].shape
+        rows = prefill_group_rows(cfg, B, S)
+        if rows == B:
+            return self._prefill(params, batch, max_seq)
+        max_seq = max_seq or jax.eval_shape(self._embed_inputs, params, batch)[0].shape[1]
+
+        def group(g, carry):
+            logits, cache = carry
+            # M-RoPE positions [3, B, S] hold the batch second.
+            part = {k: jax.lax.dynamic_slice_in_dim(
+                        v, g * rows, rows, axis=v.ndim - 2 if k == "positions" else 0)
+                    for k, v in batch.items()}
+            part_logits, part_cache = self._prefill(params, part, max_seq)
+            logits = jax.lax.dynamic_update_slice_in_dim(logits, part_logits, g * rows, axis=0)
+            return logits, jax.tree.map(partial(_write_rows, start=g * rows), cache, part_cache)
+
+        logits = jnp.zeros((B, 1, cfg.vocab_size), jnp.float32)
+        return jax.lax.fori_loop(0, B // rows, group, (logits, self.init_cache(B, max_seq)))
+
+    def _prefill(self, params, batch, max_seq: int | None = None):
         cfg = self.cfg
         with jax.named_scope("embed"):
             x, positions = self._embed_inputs(params, batch)

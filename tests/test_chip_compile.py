@@ -36,6 +36,11 @@ TRAIN_LAYERS, TRAIN_BATCH, TRAIN_SEQ, TRAIN_LIMIT = 2, 1, 2048, 2 * 2**30
 # A serving step of qwen3-4b cut to 2 layers: batch 4 over a 4,096-position
 # cache (K and V stacked over the layers: 0.125 GiB).
 DECODE_LAYERS, DECODE_BATCH, DECODE_MAX_SEQ = 2, 4, 4096
+# The deepseek-v2-lite.serve.ctx16k cell: one chip's expert share of
+# deepseek-v2-lite-16b cut to 9 layers, batch 32 prompts of 16,384 tokens over
+# a 16,640-position latent cache (5.71 GiB with its lanes padded to 640).
+MLA_ARCH, MLA_LAYERS, MLA_BATCH, MLA_PROMPT, MLA_MAX_SEQ = (
+    "deepseek-v2-lite-16b-ep8", 9, 32, 16384, 16640)
 
 
 @pytest.fixture(scope="module")
@@ -151,6 +156,47 @@ def test_decode_step_writes_the_donated_cache_in_place(one_chip):
     cache_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
     assert mem.alias_size_in_bytes == cache_bytes
     assert mem.temp_size_in_bytes <= 0.60 * 2**30
+
+
+def _footprint(mem) -> int:
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
+            - mem.alias_size_in_bytes)
+
+
+def test_mla_decode_step_fits_and_writes_the_latent_cache_in_place(one_chip):
+    """The deepseek cell's decode step fits the chip, its output cache is the
+    donated one, and the stacked latent cache is never copied whole. The
+    temporaries are 2.13 GiB, most of them the hoisted bf16 weight casts; a
+    batch-major cache made XLA relay the stacked latent out and back each
+    step (5.87 GiB), and a 576-wide one did not fit (17.15 GiB in all)."""
+    model = build_model(launch_config(MLA_ARCH, layers=MLA_LAYERS))
+    cache = jax.eval_shape(lambda: model.init_cache(MLA_BATCH, MLA_MAX_SEQ))
+    args = _on(one_chip, (
+        model.init_shapes(), cache,
+        jax.ShapeDtypeStruct((MLA_BATCH, 1), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32),
+    ))
+    compiled = jax.jit(model.decode_step, donate_argnums=(1,)).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert mem.temp_size_in_bytes <= 2.5 * 2**30
+    assert _footprint(mem) <= HBM_BYTES
+
+
+def test_mla_prefill_in_row_groups_fits(one_chip):
+    """The deepseek cell's prefill takes its 32 prompts two rows at a time,
+    each group writing its rows into the one cache of the batch, and fits."""
+    from repro.models.transformer import prefill_group_rows
+
+    cfg = launch_config(MLA_ARCH, layers=MLA_LAYERS)
+    model = build_model(cfg)
+    assert prefill_group_rows(cfg, MLA_BATCH, MLA_PROMPT) == 2
+    args = _on(one_chip, (
+        model.init_shapes(), {"tokens": jax.ShapeDtypeStruct((MLA_BATCH, MLA_PROMPT), jnp.int32)},
+    ))
+    compiled = jax.jit(lambda p, b: model.prefill(p, b, max_seq=MLA_MAX_SEQ)).lower(*args).compile()
+    assert _footprint(compiled.memory_analysis()) <= HBM_BYTES
 
 
 def test_described_chip_is_the_planners_v5e(topo):

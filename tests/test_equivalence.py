@@ -8,7 +8,6 @@ Exercises: full cache, sliding-window ring cache past the window, MLA
 compressed cache (absorbed decode), Mamba recurrent state, hybrid both.
 """
 
-import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -38,11 +37,6 @@ def _batch_for(cfg, tokens):
 
 def _smoke_model(arch):
     cfg = get_smoke_config(arch)
-    if cfg.num_experts:
-        # Capacity dropping is batch-composition-dependent by design (same
-        # tokens rank differently in a 25- vs 1-token batch); raise capacity
-        # so the equivalence check isolates the cache/state handoff.
-        cfg = dataclasses.replace(cfg, capacity_factor=8.0)
     return cfg, build_model(cfg)
 
 
@@ -87,3 +81,29 @@ def test_consecutive_decode_steps_match_full_forward(arch):
             err_msg=f"decode step {step}",
         )
         toks = jnp.concatenate([toks, jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)], 1)
+
+
+@pytest.mark.parametrize(
+    "arch", [a for a in list_archs() if not get_smoke_config(a).is_encoder_decoder])
+def test_prefill_in_row_groups_matches_one_pass(arch, monkeypatch):
+    """A batch prefilled a row at a time, each row written into the batch's
+    one cache, gives the logits and the cache of one pass over the batch."""
+    from repro.models import transformer
+
+    cfg, model = _smoke_model(arch)
+    params = model.init(jax.random.PRNGKey(1))
+    B, T = 2, 24
+    extra = cfg.num_patch_tokens if cfg.frontend == "vision_stub" else 0
+    toks = jax.random.randint(jax.random.PRNGKey(7), (B, T), 0, cfg.vocab_size, jnp.int32)
+    batch = _batch_for(cfg, toks)
+
+    def prefill():
+        return jax.jit(lambda p, b: model.prefill(p, b, max_seq=T + 4 + extra))(params, batch)
+
+    assert transformer.prefill_group_rows(cfg, B, T) == B
+    want = prefill()
+    monkeypatch.setattr(transformer, "PREFILL_GROUP_BYTES", 0)
+    assert transformer.prefill_group_rows(cfg, B, T) == 1
+    got = prefill()
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), **TOL)

@@ -74,6 +74,7 @@ def test_full_config_exact_assignment(arch):
         "starcoder2-7b": (32, 4608, 36, 4, 18432, 49_152),
         "gemma2-9b": (42, 3584, 16, 8, 14336, 256_000),
         "deepseek-v2-lite-16b": (27, 2048, 16, 16, 10944, 102_400),
+        "deepseek-v2-lite-16b-ep8": (27, 2048, 16, 16, 10944, 102_400),
         "llama4-maverick-400b-a17b": (48, 5120, 40, 8, 16384, 202_048),
         "qwen2-vl-7b": (28, 3584, 28, 4, 18944, 152_064),
         "mamba2-370m": (48, 1024, 0, 0, 0, 50_280),

@@ -13,7 +13,8 @@ from repro.core.trace import trace_step_fn
 from repro.models import build_model
 
 SERVE_SCOPES = {"embed", "layers", "attn", "qkv", "kv_write", "sdpa", "out_proj", "ffn", "head"}
-ALL_SCOPES = SERVE_SCOPES | {"mla", "mamba", "hybrid", "cross_attn", "moe"}
+MOE_SCOPES = {"route", "dispatch", "experts", "combine", "shared"}
+ALL_SCOPES = SERVE_SCOPES | MOE_SCOPES | {"mla", "mamba", "hybrid", "cross_attn", "moe"}
 
 
 def compiled_scopes(fn, *args) -> set[str]:
@@ -51,6 +52,18 @@ def test_mamba2_serving_programs_carry_the_mamba_scope(mode):
         found = compiled_scopes(lambda p, b: model.prefill(p, b, max_seq=16), params, prompt)
     assert {"embed", "layers", "mamba", "head"} <= found
     assert not found & {"attn", "sdpa", "kv_write"}
+
+
+@pytest.mark.parametrize("mode", ["decode", "prefill"])
+def test_mla_moe_serving_programs_carry_the_attention_and_expert_scopes(mode):
+    model, params, cache, tok, pos, prompt = serve_args("deepseek-v2-lite-16b-ep8")
+    if mode == "decode":
+        found = compiled_scopes(model.decode_step, params, cache, tok, pos)
+    else:
+        found = compiled_scopes(lambda p, b: model.prefill(p, b, max_seq=16), params, prompt)
+    assert {"embed", "layers", "mla", "qkv", "kv_write", "sdpa", "out_proj", "ffn", "moe",
+            "head"} | MOE_SCOPES <= found
+    assert "attn" not in found
 
 
 def test_named_scopes_stay_out_of_the_planners_trace():
