@@ -8,8 +8,9 @@ Three execution paths share exact semantics:
   * decode  — single-token step against a full or ring KV cache.  The step
               writes the new token's k and v, one slot, in place into the
               cache that the layer loop carries (``layers.CacheView``), then
-              reads the layer's cache back for attention; it never copies
-              the cache whole.
+              reads the layer's cache back for attention, which contracts
+              each KV head with its query group; it never copies the cache
+              whole nor expands it to the query heads.
 
 Keys are cached post-RoPE.  Ring caches (window layers) store absolute slot
 positions implicitly: slot j at decode position p was written at
@@ -67,13 +68,14 @@ def _softcap(scores, cap):
 
 
 def _expand_kv(k, G: int):
-    """[B,S,KV,hd] -> [B,S,KV*G,hd] broadcast.
+    """[B,S,KV,hd] -> [B,S,KV*G,hd] broadcast, for prefill and training only.
 
-    On a TPU v5e XLA materialises this broadcast instead of fusing it into
-    the matmul that reads it: the decode step writes the expanded K and V out
-    whole every step (in a profile of qwen3-4b decode at batch 4 over a
-    16,896-position cache, a ``broadcast_in_dim`` to ``bf16[4,16896,8,4,128]``
-    of 10.9 ms a step for each of K and V).
+    Those paths contract the expanded K and V on the MXU. On a TPU v5e XLA
+    materialises this broadcast instead of fusing it into the matmul that
+    reads it, which a decode step cannot afford (in a profile of qwen3-4b
+    decode at batch 4 over a 16,896-position cache, a ``broadcast_in_dim``
+    to ``bf16[4,16896,8,4,128]`` of 10.9 ms a step for each of K and V), so
+    one query position takes ``_sdpa_one_query`` instead.
     """
     if G == 1:
         return k
@@ -84,13 +86,17 @@ def _expand_kv(k, G: int):
 def _sdpa(q, k, v, mask, cfg: ModelConfig):
     """q [B,Sq,H,hd], k/v [B,Sk,KV,hd], mask broadcastable to [B,H,Sq,Sk].
 
-    Scores are [B, H, Sq, Sk] over *fused* q-heads so the partitioner can
-    shard them on H; when H doesn't divide the model axis (llama4: 40 heads,
-    hymba: 25), shard_pick falls back to query-seq then key-seq sharding
+    One query position (the decode step) takes ``_sdpa_one_query``; more
+    (prefill, training) expand K and V to the H query heads.  Scores are
+    [B, H, Sq, Sk] over *fused* q-heads so the partitioner can shard them on
+    H; when H doesn't divide the model axis (llama4: 40 heads, hymba: 25),
+    shard_pick falls back to query-seq then key-seq sharding
     (context-parallel / split-KV) — otherwise scores replicate at
     O(S^2 * H) per device.
     """
     with jax.named_scope("sdpa"):
+        if q.shape[1] == 1:
+            return _sdpa_one_query(q, k, v, mask, cfg)
         B, Sq, H, hd = q.shape
         G = H // k.shape[2]
         k, v = _expand_kv(k, G), _expand_kv(v, G)
@@ -107,6 +113,42 @@ def _sdpa(q, k, v, mask, cfg: ModelConfig):
         )
         w = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
         return jnp.einsum("bhqs,bshd->bqhd", w, v)
+
+
+def _sdpa_one_query(q, k, v, mask, cfg: ModelConfig):
+    """``_sdpa`` for one query position: q [B,1,H,hd], k/v [B,W,KV,hd], mask
+    [b,1,1,W] with b in (1, B), or None.
+
+    Each KV head is contracted with its G = H/KV query heads directly, as a
+    multiply and a float32 sum over ``hd`` (scores [B,W,KV,G]) and then over
+    W (values): XLA fuses the broadcast of q, and of the softmax weights,
+    into the reduction that reads the layer's K or V once, in the cache's
+    own layout.  The plain grouped einsum (``bqkgd,bskd->bkgqs``) is not
+    used: on a TPU v5e it lowers to a convolution that wants another layout
+    of the cache, and XLA then copies the whole stacked K and V of every
+    layer into that layout before the layer loop and back after it.  The
+    products stay in the compute dtype, with only the sums in float32: K or
+    V cast to float32 first made XLA relay the cache the same way.
+    """
+    B, _, H, hd = q.shape
+    KV = k.shape[2]
+    G = H // KV
+    f32 = jnp.float32
+    qg = q.reshape(B, 1, KV, G, hd)
+    scores = jnp.sum(k[:, :, :, None, :] * qg, axis=-1, dtype=f32) * _scale(cfg)
+    scores = _softcap(scores, cfg.attn_softcap)  # [B,W,KV,G]
+    if mask is not None:
+        m = mask.reshape(mask.shape[0], mask.shape[-1])[:, :, None, None]
+        scores = jnp.where(m, scores, -1e30)
+    scores = shard_pick(
+        scores,
+        ("batch", None, "kv_heads", None),
+        ("batch_full", None, None, None),
+        ("batch", "seq_model", None, None),
+    )
+    w = jax.nn.softmax(scores, axis=1).astype(q.dtype)
+    out = jnp.sum(w[..., None] * v[:, :, :, None, :], axis=1, dtype=f32)
+    return out.astype(q.dtype).reshape(B, 1, H, hd)
 
 
 def _causal_window_mask(q_pos, k_pos, causal: bool, window: int | None):
