@@ -1,5 +1,9 @@
 """Attention family: GQA full/sliding-window, softcap, qk-norm; KV caches.
 
+The token mixer of ``full`` and ``window`` layers (``transformer.MIXERS``):
+``init``, ``forward``, ``prefill``, ``init_cache`` and ``decode`` read the
+layer's ``attn`` params and write its ``kv`` cache.
+
 Three execution paths share exact semantics:
   * dense   — materialized scores; small sequences (training at 4k).
   * chunked — lax.scan over query blocks with online masking; bounds live
@@ -30,13 +34,26 @@ from repro.distributed.sharding import shard_pick
 from .layers import CacheView, rmsnorm
 from .rope import apply_rope
 
+SCOPE = "attn"
+
 # Sequences at or above this length use the chunked path in train/prefill.
 CHUNKED_THRESHOLD = 8192
 Q_BLOCK = 1024
 
 
+def _window(spec: LayerSpec) -> int | None:
+    """The sliding window a layer attends over: None where it sees every position."""
+    return spec.window if spec.attn in ("window", "hybrid") else None
+
+
 # ------------------------------------------------------------------- init
-def init_attention(key, cfg: ModelConfig, spec: LayerSpec):
+def init(keys, cfg: ModelConfig, spec: LayerSpec):
+    """The layer's ``attn`` params, made from the first of its mixer ``keys``."""
+    return {"attn": init_projections(keys[0], cfg)}
+
+
+def init_projections(key, cfg: ModelConfig):
+    """The projections of one attention: a layer's own, or its cross-attention's."""
     d, H, KV, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     ks = jax.random.split(key, 4)
     s = 1.0 / np.sqrt(d)
@@ -50,10 +67,6 @@ def init_attention(key, cfg: ModelConfig, spec: LayerSpec):
         p["q_norm"] = jnp.ones((hd,), jnp.float32)
         p["k_norm"] = jnp.ones((hd,), jnp.float32)
     return p
-
-
-def init_cross_attention(key, cfg: ModelConfig):
-    return init_attention(key, cfg, LayerSpec())
 
 
 # ---------------------------------------------------------------- scoring
@@ -231,24 +244,18 @@ def _project_out(p, out, dt):
 
 
 # ----------------------------------------------------------- train/prefill
-def apply_attention(
-    p,
-    x,
-    cfg: ModelConfig,
-    spec: LayerSpec,
-    angles,
-    *,
-    causal: bool = True,
-    impl: str | None = None,
-):
-    """Full-sequence attention (training / prefill). Returns [B,S,D]."""
+def _full_sequence(p, x, cfg: ModelConfig, spec: LayerSpec, angles, causal: bool):
+    """Attention over the whole sequence: (out [B,S,D], k, v)."""
     S = x.shape[1]
     q, k, v = _project_qkv(p, x, cfg, angles)
-    window = spec.window if spec.attn in ("window", "hybrid") else None
-    use_chunked = impl == "chunked" or (impl is None and S >= CHUNKED_THRESHOLD)
-    fn = mha_chunked if use_chunked else mha_dense
-    out = fn(q, k, v, cfg, causal=causal, window=window)
-    return _project_out(p, out, x.dtype)
+    fn = mha_chunked if S >= CHUNKED_THRESHOLD else mha_dense
+    out = fn(q, k, v, cfg, causal=causal, window=_window(spec))
+    return _project_out(p, out, x.dtype), k, v
+
+
+def forward(p, x, cfg: ModelConfig, spec: LayerSpec, angles, causal: bool = True):
+    """Training: x [B,S,D] -> [B,S,D]."""
+    return _full_sequence(p["attn"], x, cfg, spec, angles, causal)[0]
 
 
 def apply_cross_attention(p, x, enc_kv, cfg: ModelConfig):
@@ -269,20 +276,16 @@ def encode_cross_kv(p, enc_out, cfg: ModelConfig):
 
 # ------------------------------------------------------------------ cache
 def cache_len(cfg: ModelConfig, spec: LayerSpec, max_seq: int) -> int:
-    if spec.attn in ("window", "hybrid") and spec.window is not None:
-        return min(max_seq, spec.window)
-    return max_seq
+    """Slots of a layer's KV cache: a window layer keeps a ring of its window."""
+    window = _window(spec)
+    return max_seq if window is None else min(max_seq, window)
 
 
-def prefill_attention(p, x, cfg: ModelConfig, spec: LayerSpec, angles, max_seq: int):
-    """Full-sequence attention that also emits the filled KV cache."""
+def prefill(p, x, cfg: ModelConfig, spec: LayerSpec, angles, max_seq: int):
+    """Full-sequence attention that also emits the filled ``kv`` cache."""
     dt = x.dtype
     B, S, _ = x.shape
-    q, k, v = _project_qkv(p, x, cfg, angles)
-    window = spec.window if spec.attn in ("window", "hybrid") else None
-    fn = mha_chunked if S >= CHUNKED_THRESHOLD else mha_dense
-    out = fn(q, k, v, cfg, causal=True, window=window)
-    out = _project_out(p, out, dt)
+    out, k, v = _full_sequence(p["attn"], x, cfg, spec, angles, causal=True)
 
     W = cache_len(cfg, spec, max_seq)
     with jax.named_scope("kv_write"):
@@ -297,36 +300,36 @@ def prefill_attention(p, x, cfg: ModelConfig, spec: LayerSpec, angles, max_seq: 
                 "k": jnp.zeros((B, W) + k.shape[2:], dt).at[:, slots].set(k[:, pos]),
                 "v": jnp.zeros((B, W) + v.shape[2:], dt).at[:, slots].set(v[:, pos]),
             }
-    return out, cache
+    return out, {"kv": cache}
 
 
-def init_kv_cache(cfg: ModelConfig, spec: LayerSpec, batch: int, max_seq: int, dtype):
-    """Zeroed cache for one layer. Window layers get a ring of size window."""
-    size = max_seq
-    if spec.attn in ("window", "hybrid") and spec.window is not None:
-        size = min(max_seq, spec.window)
-    shape = (batch, size, cfg.num_kv_heads, cfg.head_dim)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+def init_cache(cfg: ModelConfig, spec: LayerSpec, batch: int, max_seq: int, dtype):
+    """The layer's zeroed ``kv`` cache of ``cache_len`` slots."""
+    shape = (batch, cache_len(cfg, spec, max_seq), cfg.num_kv_heads, cfg.head_dim)
+    return {"kv": {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}}
 
 
-def decode_attention(p, x, cache: CacheView, pos, cfg: ModelConfig, spec: LayerSpec, angles):
-    """One-token decode. x [B,1,D]; pos scalar int32; ``cache`` views the
-    layer's ``k`` and ``v`` [B,W,KV,hd]. Returns (out, cache) with the token's
-    k and v written in place at its slot."""
+def decode(p, x, cache, index, pos, cfg: ModelConfig, spec: LayerSpec, angles):
+    """One-token decode. x [B,1,D]; pos scalar int32; ``cache`` holds the
+    layer's ``kv``, ``k`` and ``v`` [B,W,KV,hd] (with ``index``, stacked; see
+    ``CacheView``). Returns (out, its ``kv``) with the token's k and v written
+    in place at its slot."""
+    p = p["attn"]
+    kv = CacheView(cache["kv"], index)
     q, k, v = _project_qkv(p, x, cfg, angles)
 
     # Unified ring-buffer update: full caches are rings of size max_seq, so
     # slot == pos and the validity mask reduces to idx <= pos; window caches
     # wrap and the mask keeps exactly the last `window` positions.
-    W = cache.shape("k")[1]
+    W = kv.shape("k")[1]
     slot = pos % W
     with jax.named_scope("kv_write"):
         at = (0, slot, 0, 0)
-        cache = cache.write("k", k, at).write("v", v, at)
-        ck, cv = cache.read("k"), cache.read("v")
+        kv = kv.write("k", k, at).write("v", v, at)
+        ck, cv = kv.read("k"), kv.read("v")
 
     idx = jnp.arange(W)
     written_at = pos - jnp.mod(pos - idx, W)  # last write position of slot idx
     mask = written_at >= 0
     out = _sdpa(q, ck, cv, mask[None, None, None, :], cfg)
-    return _project_out(p, out, x.dtype), cache
+    return _project_out(p, out, x.dtype), {"kv": kv.tree}

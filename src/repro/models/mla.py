@@ -12,6 +12,9 @@ vanilla GQA — the 512+64 vs 4096 compression that makes 32k decode cheap.
 
 Scopes as in GQA: ``qkv`` (projections, compression, rope key, W_uk),
 ``kv_write``, ``sdpa`` (scores, mask, softmax, context), ``out_proj``.
+
+The token mixer of ``mla`` layers (``transformer.MIXERS``): it reads the
+layer's ``attn`` params and writes its ``kv`` cache, the latent.
 """
 
 from __future__ import annotations
@@ -26,8 +29,11 @@ from .attention import CHUNKED_THRESHOLD, Q_BLOCK
 from .layers import CacheView, rmsnorm
 from .rope import apply_rope, yarn_mscale, yarn_rope_factor
 
+SCOPE = "mla"
 
-def init_mla(key, cfg: ModelConfig, spec: LayerSpec):
+
+def init(keys, cfg: ModelConfig, spec: LayerSpec):
+    """The layer's ``attn`` params, made from the first of its mixer ``keys``."""
     d, H = cfg.d_model, cfg.num_heads
     nope, rope_d, vh, lora = (
         cfg.qk_nope_head_dim,
@@ -35,10 +41,10 @@ def init_mla(key, cfg: ModelConfig, spec: LayerSpec):
         cfg.v_head_dim,
         cfg.kv_lora_rank,
     )
-    ks = jax.random.split(key, 6)
+    ks = jax.random.split(keys[0], 6)
     s = 1.0 / np.sqrt(d)
     sl = 1.0 / np.sqrt(lora)
-    return {
+    return {"attn": {
         "wq": jax.random.normal(ks[0], (d, H, nope + rope_d), jnp.float32) * s,
         "w_dkv": jax.random.normal(ks[1], (d, lora), jnp.float32) * s,
         "kv_norm": jnp.ones((lora,), jnp.float32),
@@ -46,7 +52,7 @@ def init_mla(key, cfg: ModelConfig, spec: LayerSpec):
         "w_uk": jax.random.normal(ks[3], (lora, H, nope), jnp.float32) * sl,
         "w_uv": jax.random.normal(ks[4], (lora, H, vh), jnp.float32) * sl,
         "wo": jax.random.normal(ks[5], (H, vh, d), jnp.float32) / np.sqrt(H * vh),
-    }
+    }}
 
 
 def _mla_scale(cfg: ModelConfig) -> float:
@@ -136,9 +142,9 @@ def _mla(p, x, cfg: ModelConfig, angles, causal: bool):
     return out, c_kv, k_rope
 
 
-def apply_mla(p, x, cfg: ModelConfig, spec: LayerSpec, angles, *, causal=True):
-    """Training/prefill MLA (decompressed). x [B,S,D] -> [B,S,D]."""
-    return _mla(p, x, cfg, angles, causal)[0]
+def forward(p, x, cfg: ModelConfig, spec: LayerSpec, angles, causal: bool = True):
+    """Training MLA (decompressed). x [B,S,D] -> [B,S,D]."""
+    return _mla(p["attn"], x, cfg, angles, causal)[0]
 
 
 def _latent_width(cfg: ModelConfig) -> int:
@@ -155,30 +161,34 @@ def _latent(c_kv, k_rope, cfg: ModelConfig):
     return _pad_to(jnp.concatenate([c_kv, k_rope], axis=-1), _latent_width(cfg)).swapaxes(0, 1)
 
 
-def prefill_mla(p, x, cfg: ModelConfig, spec: LayerSpec, angles, max_seq: int):
+def prefill(p, x, cfg: ModelConfig, spec: LayerSpec, angles, max_seq: int):
     """MLA prefill emitting the latent cache (below)."""
-    out, c_kv, k_rope = _mla(p, x, cfg, angles, causal=True)
+    out, c_kv, k_rope = _mla(p["attn"], x, cfg, angles, causal=True)
     S = x.shape[1]
     with jax.named_scope("kv_write"):
         latent = _latent(c_kv, k_rope, cfg)
-        return out, {"latent": jnp.pad(latent, [(0, max_seq - S), (0, 0), (0, 0)])}
+        return out, {"kv": {"latent": jnp.pad(latent, [(0, max_seq - S), (0, 0), (0, 0)])}}
 
 
-def init_mla_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype):
-    """The latent cache [max_seq, batch, latent width]: each position's c_kv,
-    then its rope key, then zeros to a whole number of 128 lanes. So laid
-    out, XLA keeps the donated cache's own layout through the decode step.
-    Otherwise it relays the whole stacked cache out and back each step:
-    batch-major, since a slot write then touches a partial (8, 128) tile of
-    every sequence (4 GiB at batch 32 over 16,640 positions); 576 lanes
-    wide, or with the 64-wide rope key in an array of its own, since it lays
-    those out positions-minor for the absorbed products."""
-    return {"latent": jnp.zeros((max_seq, batch, _latent_width(cfg)), dtype)}
+def init_cache(cfg: ModelConfig, spec: LayerSpec, batch: int, max_seq: int, dtype):
+    """The layer's ``kv``: the latent cache [max_seq, batch, latent width],
+    each position's c_kv, then its rope key, then zeros to a whole number of
+    128 lanes. So laid out, XLA keeps the donated cache's own layout through
+    the decode step. Otherwise it relays the whole stacked cache out and
+    back each step: batch-major, since a slot write then touches a partial
+    (8, 128) tile of every sequence (4 GiB at batch 32 over 16,640
+    positions); 576 lanes wide, or with the 64-wide rope key in an array of
+    its own, since it lays those out positions-minor for the absorbed
+    products."""
+    return {"kv": {"latent": jnp.zeros((max_seq, batch, _latent_width(cfg)), dtype)}}
 
 
-def decode_mla(p, x, cache: CacheView, pos, cfg: ModelConfig, spec: LayerSpec, angles):
-    """Absorbed one-token decode against the latent cache; ``cache`` views
-    the layer's ``latent``, written in place at slot ``pos``."""
+def decode(p, x, cache, index, pos, cfg: ModelConfig, spec: LayerSpec, angles):
+    """Absorbed one-token decode against the latent cache: the layer's
+    ``kv`` (with ``index``, stacked; see ``CacheView``), its ``latent``
+    written in place at slot ``pos``."""
+    p = p["attn"]
+    kv = CacheView(cache["kv"], index)
     dt = x.dtype
     with jax.named_scope("qkv"):
         q_nope, q_rope = _project_q(p, x, cfg, angles)          # [B,1,H,*]
@@ -188,8 +198,8 @@ def decode_mla(p, x, cache: CacheView, pos, cfg: ModelConfig, spec: LayerSpec, a
         q_abs = jnp.einsum("bqhk,lhk->bqhl", q_nope, p["w_uk"].astype(dt))  # [B,1,H,lora]
         q_lat = _pad_to(jnp.concatenate([q_abs, q_rope], axis=-1), _latent_width(cfg))
     with jax.named_scope("kv_write"):
-        cache = cache.write("latent", _latent(c_new, kr_new, cfg), (pos, 0, 0))
-        latent = cache.read("latent")                           # [S,B,latent width]
+        kv = kv.write("latent", _latent(c_new, kr_new, cfg), (pos, 0, 0))
+        latent = kv.read("latent")                              # [S,B,latent width]
 
     with jax.named_scope("sdpa"):
         scores = jnp.einsum("bqhl,sbl->bhqs", q_lat, latent) * _mla_scale(cfg)
@@ -202,4 +212,4 @@ def decode_mla(p, x, cache: CacheView, pos, cfg: ModelConfig, spec: LayerSpec, a
     with jax.named_scope("out_proj"):
         out = jnp.einsum("bqhl,lhk->bqhk", ctx, p["w_uv"].astype(dt))  # absorb W_uv
         out = jnp.einsum("bqhk,hkd->bqd", out, p["wo"].astype(dt))
-    return out, cache
+    return out, {"kv": kv.tree}

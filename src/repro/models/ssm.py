@@ -9,6 +9,9 @@ plus a [B, conv_dim, K-1] convolution tail — this is what makes the
 
 Layout: d_inner = expand*d_model, heads H = d_inner/headdim (P=headdim),
 state N = ssm_state, G groups share B/C across H/G heads.
+
+The token mixer of ``mamba`` layers (``transformer.MIXERS``): it reads the
+layer's ``mamba`` params and writes its ``ssm`` cache.
 """
 
 from __future__ import annotations
@@ -17,8 +20,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import LayerSpec, ModelConfig
 from .layers import CacheView, rmsnorm
+
+SCOPE = "mamba"
 
 
 def _dims(cfg: ModelConfig):
@@ -31,13 +36,14 @@ def _dims(cfg: ModelConfig):
     return di, H, P, N, G, conv_dim
 
 
-def init_mamba(key, cfg: ModelConfig):
+def init(keys, cfg: ModelConfig, spec: LayerSpec):
+    """The layer's ``mamba`` params, made from the first of its mixer ``keys``."""
     d = cfg.d_model
     di, H, P, N, G, conv_dim = _dims(cfg)
-    ks = jax.random.split(key, 4)
+    ks = jax.random.split(keys[0], 4)
     s = 1.0 / np.sqrt(d)
     proj_out = 2 * di + 2 * G * N + H  # z, x, B, C, dt
-    return {
+    return {"mamba": {
         "in_proj": jax.random.normal(ks[0], (d, proj_out), jnp.float32) * s,
         "conv_w": jax.random.normal(ks[1], (cfg.conv_kernel, conv_dim), jnp.float32) * 0.1,
         "conv_b": jnp.zeros((conv_dim,), jnp.float32),
@@ -46,7 +52,7 @@ def init_mamba(key, cfg: ModelConfig):
         "dt_bias": jnp.zeros((H,), jnp.float32),
         "norm": jnp.ones((di,), jnp.float32),
         "out_proj": jax.random.normal(ks[2], (di, d), jnp.float32) / np.sqrt(di),
-    }
+    }}
 
 
 def _split_proj(zxbcdt, cfg: ModelConfig):
@@ -135,12 +141,8 @@ def ssd_chunked(x, dt, A, Bm, Cm, chunk: int):
     return y, final_state
 
 
-def apply_mamba(p, x_in, cfg: ModelConfig, *, return_cache: bool = False):
-    """x_in [B,S,D] -> [B,S,D] (training / prefill).
-
-    ``return_cache=True`` additionally emits the recurrent decode cache
-    (final SSM state + conv tail) so prefill can hand off to decode_mamba.
-    """
+def _mamba(p, x_in, cfg: ModelConfig):
+    """x_in [B,S,D] -> (out [B,S,D], final SSM state, conv input [B,S,conv])."""
     dt_ = x_in.dtype
     B_, S = x_in.shape[:2]
     zxbcdt = jnp.einsum("bsd,dk->bsk", x_in, p["in_proj"].astype(dt_))
@@ -164,32 +166,45 @@ def apply_mamba(p, x_in, cfg: ModelConfig, *, return_cache: bool = False):
     y = y.reshape(B_, S, -1)
     y = rmsnorm(p["norm"], y * jax.nn.silu(z), cfg.norm_eps)
     out = jnp.einsum("bsk,kd->bsd", y, p["out_proj"].astype(dt_))
-    if not return_cache:
-        return out
-    K = cfg.conv_kernel
-    cache = {"state": final_state, "conv": xBC_raw[:, S - (K - 1) :, :]}
-    return out, cache
+    return out, final_state, xBC_raw
+
+
+def forward(p, x, cfg: ModelConfig, spec: LayerSpec, angles, causal: bool = True):
+    """Training: x [B,S,D] -> [B,S,D]."""
+    return _mamba(p["mamba"], x, cfg)[0]
+
+
+def prefill(p, x, cfg: ModelConfig, spec: LayerSpec, angles, max_seq: int):
+    """The forward pass that also emits the ``ssm`` cache decode goes on
+    from: the final SSM state and the conv tail."""
+    out, final_state, xBC_raw = _mamba(p["mamba"], x, cfg)
+    S, K = x.shape[1], cfg.conv_kernel
+    return out, {"ssm": {"state": final_state, "conv": xBC_raw[:, S - (K - 1) :, :]}}
 
 
 # ------------------------------------------------------------------ decode
-def init_mamba_cache(cfg: ModelConfig, batch: int, dtype):
+def init_cache(cfg: ModelConfig, spec: LayerSpec, batch: int, max_seq: int, dtype):
+    """The layer's zeroed ``ssm`` cache, the same size at any ``max_seq``."""
     di, H, P, N, G, conv_dim = _dims(cfg)
-    return {
+    return {"ssm": {
         "state": jnp.zeros((batch, H, P, N), jnp.float32),
         "conv": jnp.zeros((batch, cfg.conv_kernel - 1, conv_dim), dtype),
-    }
+    }}
 
 
-def decode_mamba(p, x_in, cache: CacheView, cfg: ModelConfig):
-    """One-token recurrent step. x_in [B,1,D] -> ([B,1,D], cache); ``cache``
-    views the layer's ``state`` and ``conv`` tail, both written whole."""
+def decode(p, x_in, cache, index, pos, cfg: ModelConfig, spec: LayerSpec, angles):
+    """One-token recurrent step. x_in [B,1,D] -> ([B,1,D], its ``ssm``);
+    ``cache`` holds the layer's ``ssm`` (with ``index``, stacked; see
+    ``CacheView``), its ``state`` and ``conv`` tail both written whole."""
+    p = p["mamba"]
+    view = CacheView(cache["ssm"], index)
     dt_ = x_in.dtype
     di, H, P, N, G, conv_dim = _dims(cfg)
     zxbcdt = jnp.einsum("bsd,dk->bsk", x_in, p["in_proj"].astype(dt_))
     z, xBC_new, dt_raw = _split_proj(zxbcdt, cfg)
 
     # conv over [cached K-1 tail, new column]
-    window = jnp.concatenate([cache.read("conv"), xBC_new], axis=1)     # [B,K,conv]
+    window = jnp.concatenate([view.read("conv"), xBC_new], axis=1)     # [B,K,conv]
     conv_out = (window * p["conv_w"].astype(dt_)[None]).sum(1, keepdims=True)
     xBC = jax.nn.silu(conv_out + p["conv_b"].astype(dt_))
     new_conv = window[:, 1:]
@@ -203,7 +218,7 @@ def decode_mamba(p, x_in, cache: CacheView, cfg: ModelConfig):
     A = -jnp.exp(p["A_log"])
     dA = jnp.exp(dt * A)                                            # [B,H]
 
-    state = cache.read("state") * dA[..., None, None] + jnp.einsum(
+    state = view.read("state") * dA[..., None, None] + jnp.einsum(
         "bhp,bhn,bh->bhpn", x.astype(jnp.float32), Bh.astype(jnp.float32), dt
     )
     y = jnp.einsum("bhpn,bhn->bhp", state, Ch.astype(jnp.float32)).astype(dt_)
@@ -211,5 +226,5 @@ def decode_mamba(p, x_in, cache: CacheView, cfg: ModelConfig):
     y = y.reshape(x_in.shape[0], 1, di)
     y = rmsnorm(p["norm"], y * jax.nn.silu(z), cfg.norm_eps)
     out = jnp.einsum("bsk,kd->bsd", y, p["out_proj"].astype(dt_))
-    cache = cache.write("state", state, (0, 0, 0, 0)).write("conv", new_conv, (0, 0, 0))
-    return out, cache
+    view = view.write("state", state, (0, 0, 0, 0)).write("conv", new_conv, (0, 0, 0))
+    return out, {"ssm": view.tree}

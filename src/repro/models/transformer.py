@@ -11,6 +11,10 @@ Layers are grouped into program segments (configs/base.py); segments with
 repeats > 1 run under ``lax.scan`` with stacked params, which keeps compile
 time flat in depth and makes remat/offload policies uniform per layer class
 (the granularity AutoSwap's planner operates on — see core/offload.py).
+
+Each mode has its own walker over the segments (``apply_program``,
+``prefill_program``, ``decode_program``), and every walker runs each layer
+through the one ``layer`` body, which takes its token mixer from ``MIXERS``.
 """
 
 from __future__ import annotations
@@ -25,260 +29,105 @@ from jax.ad_checkpoint import checkpoint_name
 
 from repro.configs.base import LayerSpec, ModelConfig, Segment
 from repro.distributed.sharding import shard
-from . import attention as attn_mod
-from . import mla as mla_mod
-from . import moe as moe_mod
-from . import ssm as ssm_mod
+from . import attention, hybrid, mla, moe, ssm
 from .layers import (
     CacheView,
     apply_dense_ffn,
     apply_norm,
     chunked_softmax_xent,
-    cross_entropy,
     dtype_of,
     embed_tokens,
     init_dense_ffn,
     init_embedding,
     init_norm,
     lm_logits,
-    rmsnorm,
 )
 from .rope import mrope_angles, rope_angles
 
 # ---------------------------------------------------------------- layers
 
-# The named scope of each token-mixing block (``LayerSpec.attn``). Every block
-# of a layer runs under one scope that holds its norm, the block and its
-# residual add, so the compiled program's ``op_name`` metadata, and with it a
-# profile of the program, names the block an op belongs to.
-MIX_SCOPE = {"full": "attn", "window": "attn", "mla": "mla", "mamba": "mamba",
-             "hybrid": "hybrid"}
+# The token mixer of each kind of layer (``LayerSpec.attn``): a module with
+# the same entry points, each reading and writing only its own entries of a
+# layer's params (``attn``, ``mamba``, ...) and cache (``kv``, ``ssm``):
+#
+#   SCOPE                                               its named scope
+#   init(keys, cfg, spec)                               -> its params
+#   forward(p, h, cfg, spec, angles, causal)            -> h  (training)
+#   prefill(p, h, cfg, spec, angles, max_seq)           -> (h, its cache)
+#   init_cache(cfg, spec, batch, max_seq, dtype)        -> its cache
+#   decode(p, h, cache, index, pos, cfg, spec, angles)  -> (h, its cache)
+#
+# ``decode`` reads and writes through ``CacheView``. A new kind of mixer is
+# one such module and its row here.
+MIXERS = {"full": attention, "window": attention, "mla": mla, "mamba": ssm, "hybrid": hybrid}
 
 
 def init_layer(key, cfg: ModelConfig, spec: LayerSpec):
     ks = jax.random.split(key, 6)
     p: dict[str, Any] = {"ln1": init_norm(cfg)}
-    if spec.attn in ("full", "window"):
-        p["attn"] = attn_mod.init_attention(ks[0], cfg, spec)
-    elif spec.attn == "mla":
-        p["attn"] = mla_mod.init_mla(ks[0], cfg, spec)
-    elif spec.attn == "mamba":
-        p["mamba"] = ssm_mod.init_mamba(ks[0], cfg)
-    elif spec.attn == "hybrid":
-        p["attn"] = attn_mod.init_attention(ks[0], cfg, spec)
-        p["mamba"] = ssm_mod.init_mamba(ks[1], cfg)
-        p["branch_norm_a"] = jnp.ones((cfg.d_model,), jnp.float32)
-        p["branch_norm_m"] = jnp.ones((cfg.d_model,), jnp.float32)
-    if cfg.sandwich_norms and spec.attn != "none":
-        p["ln1_post"] = init_norm(cfg)
+    if spec.attn != "none":
+        # The mixer's keys are ks[0] and ks[1] (hybrid's Mamba).
+        p.update(MIXERS[spec.attn].init(ks[:2], cfg, spec))
+        if cfg.sandwich_norms:
+            p["ln1_post"] = init_norm(cfg)
     if spec.cross_attn:
         p["ln_cross"] = init_norm(cfg)
-        p["cross"] = attn_mod.init_cross_attention(ks[2], cfg)
-    if spec.ffn == "dense":
+        p["cross"] = attention.init_projections(ks[2], cfg)
+    if spec.ffn != "none":
         p["ln2"] = init_norm(cfg)
-        p["ffn"] = init_dense_ffn(ks[3], cfg)
-        if cfg.sandwich_norms:
-            p["ln2_post"] = init_norm(cfg)
-    elif spec.ffn == "moe":
-        p["ln2"] = init_norm(cfg)
-        p["moe"] = moe_mod.init_moe(ks[4], cfg)
+        if spec.ffn == "dense":
+            p["ffn"] = init_dense_ffn(ks[3], cfg)
+        else:
+            p["moe"] = moe.init_moe(ks[4], cfg)
         if cfg.sandwich_norms:
             p["ln2_post"] = init_norm(cfg)
     return p
 
 
-def _mix(p, h, cfg, spec, angles, causal):
-    """The token-mixing sublayer (attention family)."""
-    if spec.attn in ("full", "window"):
-        return attn_mod.apply_attention(p["attn"], h, cfg, spec, angles, causal=causal)
-    if spec.attn == "mla":
-        return mla_mod.apply_mla(p["attn"], h, cfg, spec, angles, causal=causal)
-    if spec.attn == "mamba":
-        return ssm_mod.apply_mamba(p["mamba"], h, cfg)
-    if spec.attn == "hybrid":
-        a = attn_mod.apply_attention(p["attn"], h, cfg, spec, angles, causal=causal)
-        m = ssm_mod.apply_mamba(p["mamba"], h, cfg)
-        return 0.5 * (
-            rmsnorm(p["branch_norm_a"], a, cfg.norm_eps)
-            + rmsnorm(p["branch_norm_m"], m, cfg.norm_eps)
-        )
-    return None
+def layer(p, x, cfg: ModelConfig, spec: LayerSpec, mix, cross_kv, train: bool = False):
+    """One layer's blocks, the same in training, prefill and decode: the token
+    mixer, cross-attention, then the FFN or MoE, each under its named scope
+    with its norm, its sandwich norm and its residual add.
 
-
-def apply_layer(p, x, cfg: ModelConfig, spec: LayerSpec, angles, enc_out=None, causal=True):
-    """Returns (x, aux_loss)."""
-    aux = jnp.zeros((), jnp.float32)
-    if spec.attn != "none":
-        with jax.named_scope(MIX_SCOPE[spec.attn]):
-            h = apply_norm(p["ln1"], x, cfg)
-            h = _mix(p, h, cfg, spec, angles, causal)
-            h = checkpoint_name(h, "attn_out")
-            if cfg.sandwich_norms:
-                h = apply_norm(p["ln1_post"], h, cfg)
-            x = x + h
-    if spec.cross_attn:
-        with jax.named_scope("cross_attn"):
-            h = apply_norm(p["ln_cross"], x, cfg)
-            kv = attn_mod.encode_cross_kv(p["cross"], enc_out, cfg)
-            h = attn_mod.apply_cross_attention(p["cross"], h, kv, cfg)
-            x = x + h
-    if spec.ffn == "dense":
-        with jax.named_scope("ffn"):
-            h = apply_norm(p["ln2"], x, cfg)
-            h = apply_dense_ffn(p["ffn"], h, cfg)
-            h = checkpoint_name(h, "ffn_out")
-            if cfg.sandwich_norms:
-                h = apply_norm(p["ln2_post"], h, cfg)
-            x = x + h
-    elif spec.ffn == "moe":
-        with jax.named_scope("moe"):
-            h = apply_norm(p["ln2"], x, cfg)
-            h, aux = moe_mod.apply_moe(p["moe"], h, cfg)
-            h = checkpoint_name(h, "ffn_out")
-            if cfg.sandwich_norms:
-                h = apply_norm(p["ln2_post"], h, cfg)
-            x = x + h
-    x = shard(x, "batch", "seq", "embed")
-    return x, aux
-
-
-# ---------------------------------------------------------------- caches
-
-
-def prefill_layer(p, x, cfg: ModelConfig, spec: LayerSpec, angles, max_seq: int, enc_out=None):
-    """Forward one layer over the whole prompt, emitting its decode cache."""
+    The mode says what differs. ``mix(mixer, p, h)`` runs its entry of the
+    layer's mixer (``MIXERS``) on the normed ``h`` and returns (h, the
+    mixer's cache entries); ``cross_kv(p)`` returns the encoder's (k, v) for
+    cross-attention and the cache entries that keep them. ``train`` names the
+    blocks' outputs for the remat and offload policies (``attn_out``,
+    ``ffn_out``; core/offload.py). Returns (x, the layer's cache, MoE aux loss).
+    """
     cache: dict[str, Any] = {}
-    if spec.attn != "none":
-        with jax.named_scope(MIX_SCOPE[spec.attn]):
-            h = apply_norm(p["ln1"], x, cfg)
-            if spec.attn in ("full", "window"):
-                h, cache["kv"] = attn_mod.prefill_attention(p["attn"], h, cfg, spec, angles, max_seq)
-                if cfg.sandwich_norms:
-                    h = apply_norm(p["ln1_post"], h, cfg)
-            elif spec.attn == "mla":
-                h, cache["kv"] = mla_mod.prefill_mla(p["attn"], h, cfg, spec, angles, max_seq)
-            elif spec.attn == "mamba":
-                h, cache["ssm"] = ssm_mod.apply_mamba(p["mamba"], h, cfg, return_cache=True)
-            else:  # hybrid
-                a, cache["kv"] = attn_mod.prefill_attention(p["attn"], h, cfg, spec, angles, max_seq)
-                m, cache["ssm"] = ssm_mod.apply_mamba(p["mamba"], h, cfg, return_cache=True)
-                h = 0.5 * (
-                    rmsnorm(p["branch_norm_a"], a, cfg.norm_eps)
-                    + rmsnorm(p["branch_norm_m"], m, cfg.norm_eps)
-                )
-            x = x + h
-    if spec.cross_attn:
-        with jax.named_scope("cross_attn"):
-            cache["enc_kv"] = attn_mod.encode_cross_kv(p["cross"], enc_out, cfg)
-            h = apply_norm(p["ln_cross"], x, cfg)
-            h = attn_mod.apply_cross_attention(p["cross"], h, cache["enc_kv"], cfg)
-            x = x + h
-    x = _serve_ffn(p, x, cfg, spec)
-    x = shard(x, "batch", "seq", "embed")
-    return x, cache
+    aux = jnp.zeros((), jnp.float32)
 
-
-def prefill_program(segs, x, cfg, program, angles, max_seq: int, enc_out=None):
-    caches = []
-    for (unit, reps), seg_params in zip(program, segs):
-
-        def unit_fn(params, x):
-            cache = {}
-            for i, spec in enumerate(unit):
-                x, cache[f"l{i}"] = prefill_layer(
-                    params[f"l{i}"], x, cfg, spec, angles, max_seq, enc_out
-                )
-            return x, cache
-
-        if reps > 1:
-
-            def body(x, params):
-                return unit_fn(params, x)
-
-            x, seg_cache = jax.lax.scan(body, x, seg_params)
-        else:
-            x, seg_cache = unit_fn(seg_params, x)
-        caches.append(seg_cache)
-    return x, caches
-
-
-def init_layer_cache(cfg: ModelConfig, spec: LayerSpec, batch: int, max_seq: int, dtype):
-    c: dict[str, Any] = {}
-    if spec.attn in ("full", "window"):
-        c["kv"] = attn_mod.init_kv_cache(cfg, spec, batch, max_seq, dtype)
-    elif spec.attn == "mla":
-        c["kv"] = mla_mod.init_mla_cache(cfg, batch, max_seq, dtype)
-    elif spec.attn == "mamba":
-        c["ssm"] = ssm_mod.init_mamba_cache(cfg, batch, dtype)
-    elif spec.attn == "hybrid":
-        c["kv"] = attn_mod.init_kv_cache(cfg, spec, batch, max_seq, dtype)
-        c["ssm"] = ssm_mod.init_mamba_cache(cfg, batch, dtype)
-    if spec.cross_attn:
-        # enc k/v get filled at prefill time
-        H, hd = cfg.num_kv_heads, cfg.head_dim
-        c["enc_kv"] = (
-            jnp.zeros((batch, cfg.enc_seq, H, hd), dtype),
-            jnp.zeros((batch, cfg.enc_seq, H, hd), dtype),
-        )
-    return c
-
-
-def decode_layer(p, x, cache, index, pos, cfg: ModelConfig, spec: LayerSpec, angles):
-    """One token through one layer. ``cache`` is the layer's entry, or with
-    ``index`` the segment's stacked entries, the layer's at ``index``; each
-    block writes what it changed in place (``CacheView``). Returns (x, cache)."""
-    new_cache = dict(cache)
-    if spec.attn != "none":
-        with jax.named_scope(MIX_SCOPE[spec.attn]):
-            h = apply_norm(p["ln1"], x, cfg)
-            if spec.attn in ("full", "window"):
-                h, kv = attn_mod.decode_attention(
-                    p["attn"], h, CacheView(cache["kv"], index), pos, cfg, spec, angles
-                )
-                new_cache["kv"] = kv.tree
-                if cfg.sandwich_norms:
-                    h = apply_norm(p["ln1_post"], h, cfg)
-            elif spec.attn == "mla":
-                h, kv = mla_mod.decode_mla(
-                    p["attn"], h, CacheView(cache["kv"], index), pos, cfg, spec, angles
-                )
-                new_cache["kv"] = kv.tree
-            elif spec.attn == "mamba":
-                h, ssm = ssm_mod.decode_mamba(p["mamba"], h, CacheView(cache["ssm"], index), cfg)
-                new_cache["ssm"] = ssm.tree
-            else:  # hybrid
-                a, kv = attn_mod.decode_attention(
-                    p["attn"], h, CacheView(cache["kv"], index), pos, cfg, spec, angles
-                )
-                m, ssm = ssm_mod.decode_mamba(p["mamba"], h, CacheView(cache["ssm"], index), cfg)
-                new_cache["kv"], new_cache["ssm"] = kv.tree, ssm.tree
-                h = 0.5 * (
-                    rmsnorm(p["branch_norm_a"], a, cfg.norm_eps)
-                    + rmsnorm(p["branch_norm_m"], m, cfg.norm_eps)
-                )
-            x = x + h
-    if spec.cross_attn:
-        with jax.named_scope("cross_attn"):
-            enc = CacheView(cache["enc_kv"], index)
-            h = apply_norm(p["ln_cross"], x, cfg)
-            h = attn_mod.apply_cross_attention(p["cross"], h, (enc.read(0), enc.read(1)), cfg)
-            x = x + h
-    return _serve_ffn(p, x, cfg, spec), new_cache
-
-
-def _serve_ffn(p, x, cfg: ModelConfig, spec: LayerSpec):
-    """The feed-forward block of prefill and decode (no aux loss, no remat names)."""
-    if spec.ffn == "none":
-        return x
-    with jax.named_scope("moe" if spec.ffn == "moe" else "ffn"):
-        h = apply_norm(p["ln2"], x, cfg)
-        if spec.ffn == "dense":
-            h = apply_dense_ffn(p["ffn"], h, cfg)
-        else:
-            h, _ = moe_mod.apply_moe(p["moe"], h, cfg)
+    def residual(x, h, name, post):
+        if train:
+            h = checkpoint_name(h, name)
         if cfg.sandwich_norms:
-            h = apply_norm(p["ln2_post"], h, cfg)
+            h = apply_norm(p[post], h, cfg)
         return x + h
+
+    if spec.attn != "none":
+        mixer = MIXERS[spec.attn]
+        with jax.named_scope(mixer.SCOPE):
+            h, entries = mix(mixer, p, apply_norm(p["ln1"], x, cfg))
+            x = residual(x, h, "attn_out", "ln1_post")
+        cache.update(entries)
+    if spec.cross_attn:
+        with jax.named_scope("cross_attn"):
+            h = apply_norm(p["ln_cross"], x, cfg)
+            kv, entries = cross_kv(p)
+            x = x + attention.apply_cross_attention(p["cross"], h, kv, cfg)
+        cache.update(entries)
+    if spec.ffn != "none":
+        block = "ffn" if spec.ffn == "dense" else "moe"
+        with jax.named_scope(block):
+            h = apply_norm(p["ln2"], x, cfg)
+            if spec.ffn == "dense":
+                h = apply_dense_ffn(p["ffn"], h, cfg)
+            else:
+                h, aux = moe.apply_moe(p["moe"], h, cfg)
+            x = residual(x, h, "ffn_out", "ln2_post")
+    return x, cache, aux
 
 
 # --------------------------------------------------------------- program
@@ -327,7 +176,13 @@ def apply_program(
             x = checkpoint_name(x, "block_in")
             aux = jnp.zeros((), jnp.float32)
             for i, spec in enumerate(unit):
-                x, a = apply_layer(params[f"l{i}"], x, cfg, spec, angles, enc_out, causal)
+                x, _, a = layer(
+                    params[f"l{i}"], x, cfg, spec,
+                    lambda m, p, h: (m.forward(p, h, cfg, spec, angles, causal), {}),
+                    lambda p: (attention.encode_cross_kv(p["cross"], enc_out, cfg), {}),
+                    train=True,
+                )
+                x = shard(x, "batch", "seq", "embed")
                 aux = aux + a
             return x, aux
 
@@ -346,6 +201,51 @@ def apply_program(
             x, a = unit_fn(seg_params, x)
             total_aux = total_aux + a
     return x, total_aux
+
+
+def prefill_program(segs, x, cfg, program, angles, max_seq: int, enc_out=None):
+    """The prompt through every segment, each layer emitting its decode
+    cache. Returns (x, caches), a segment of repeats' caches stacked."""
+
+    def enc_kv(p):
+        kv = attention.encode_cross_kv(p["cross"], enc_out, cfg)
+        return kv, {"enc_kv": kv}
+
+    caches = []
+    for (unit, reps), seg_params in zip(program, segs):
+
+        def unit_fn(params, x):
+            cache = {}
+            for i, spec in enumerate(unit):
+                x, cache[f"l{i}"], _ = layer(
+                    params[f"l{i}"], x, cfg, spec,
+                    lambda m, p, h: m.prefill(p, h, cfg, spec, angles, max_seq),
+                    enc_kv,
+                )
+                x = shard(x, "batch", "seq", "embed")
+            return x, cache
+
+        if reps > 1:
+
+            def body(x, params):
+                return unit_fn(params, x)
+
+            x, seg_cache = jax.lax.scan(body, x, seg_params)
+        else:
+            x, seg_cache = unit_fn(seg_params, x)
+        caches.append(seg_cache)
+    return x, caches
+
+
+def init_layer_cache(cfg: ModelConfig, spec: LayerSpec, batch: int, max_seq: int, dtype):
+    c = {}
+    if spec.attn != "none":
+        c = MIXERS[spec.attn].init_cache(cfg, spec, batch, max_seq, dtype)
+    if spec.cross_attn:
+        # The encoder's k and v, filled at prefill.
+        shape = (batch, cfg.enc_seq, cfg.num_kv_heads, cfg.head_dim)
+        c["enc_kv"] = (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+    return c
 
 
 def init_program_cache(cfg, program, batch, max_seq, dtype):
@@ -380,8 +280,11 @@ def decode_program(segs, caches, x, pos, cfg, program, angles):
         def unit_fn(params, cache, x, index):
             cache = dict(cache)
             for i, spec in enumerate(unit):
-                x, cache[f"l{i}"] = decode_layer(
-                    params[f"l{i}"], x, cache[f"l{i}"], index, pos, cfg, spec, angles
+                c = cache[f"l{i}"]
+                x, cache[f"l{i}"], _ = layer(
+                    params[f"l{i}"], x, cfg, spec,
+                    lambda m, p, h: m.decode(p, h, c, index, pos, cfg, spec, angles),
+                    lambda p: _read_enc_kv(c, index),
                 )
             return x, cache
 
@@ -399,6 +302,12 @@ def decode_program(segs, caches, x, pos, cfg, program, angles):
             x, seg_cache = unit_fn(seg_params, seg_cache, x, None)
         new_caches.append(seg_cache)
     return x, new_caches
+
+
+def _read_enc_kv(cache, index):
+    """Decode's cross-attention (k, v): the layer's ``enc_kv``, kept as is."""
+    enc = CacheView(cache["enc_kv"], index)
+    return (enc.read(0), enc.read(1)), {"enc_kv": cache["enc_kv"]}
 
 
 def _write_rows(cache, rows, start):

@@ -24,7 +24,7 @@ BF16_ULP = 2.0**-7
 
 
 def _mask(kind):
-    """Decode masks over a W-slot cache (``decode_attention``'s rule): a full
+    """Decode masks over a W-slot cache (``attention.decode``'s rule): a full
     cache half written, a ring past its wrap, one row per batch entry."""
     idx = jnp.arange(W)
     if kind == "none":

@@ -1,6 +1,8 @@
 """Per-architecture smoke tests: reduced config, one train step on CPU,
 output shapes + finite values.  (Deliverable f: one smoke per assigned arch.)"""
 
+from typing import get_args
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,7 +12,9 @@ from repro.configs import (
     SHAPES, cut_depth, get_config, get_smoke_config, input_specs, launch_config,
     list_archs, supports_shape,
 )
+from repro.configs.base import AttnKind
 from repro.models import build_model
+from repro.models.transformer import MIXERS
 
 
 def smoke_batch(cfg, B=2, S=32):
@@ -62,6 +66,29 @@ def test_arch_decode_smoke(arch):
     logits2, cache = model.decode_step(params, cache, tok, jnp.asarray(S + extra, jnp.int32))
     assert logits2.shape == (B, 1, cfg.vocab_size)
     assert bool(jnp.isfinite(logits2).all()), f"{arch} decode logits not finite"
+
+
+@pytest.mark.parametrize("arch", list_archs())
+def test_prefill_emits_the_cache_init_cache_lays_out(arch):
+    """Prefill's cache has init_cache's structure, shapes and dtypes, which
+    grouped prefill relies on: each group writes its rows into a cache that
+    init_cache made for the whole batch."""
+    cfg = get_smoke_config(arch)
+    model = build_model(cfg)
+    params = model.init_shapes()
+    B, S = 2, 8
+    batch = {k: v for k, v in smoke_batch(cfg, B, S).items() if k != "labels"}
+    extra = cfg.num_patch_tokens if cfg.frontend == "vision_stub" else 0
+    max_seq = 16 + extra
+    _, got = jax.eval_shape(lambda p, b: model.prefill(p, b, max_seq=max_seq), params, batch)
+    want = jax.eval_shape(lambda: model.init_cache(B, max_seq))
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert (g.shape, g.dtype) == (w.shape, w.dtype)
+
+
+def test_every_layer_kind_has_a_mixer():
+    assert set(get_args(AttnKind)) - {"none"} == set(MIXERS)
 
 
 @pytest.mark.parametrize("arch", list_archs())
